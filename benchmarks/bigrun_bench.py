@@ -131,6 +131,8 @@ def main(argv=None) -> dict:
     ap.add_argument("--small", action="store_true")
     ap.add_argument("--out", default="BENCH_bigrun.json")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     kind = "smoke" if args.smoke else ("small" if args.small else "full")
     res = run(kind)
     res["date"] = time.strftime("%Y-%m-%d")

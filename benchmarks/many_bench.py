@@ -134,6 +134,8 @@ def main(argv=None) -> dict:
                     help="CI-sized: 6 graphs, still writes the JSON")
     ap.add_argument("--out", default="BENCH_many.json")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     res = run("smoke" if args.smoke else "full")
     res["date"] = time.strftime("%Y-%m-%d")
     with open(args.out, "w") as f:

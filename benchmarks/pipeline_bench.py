@@ -307,6 +307,8 @@ def main(argv=None) -> dict:
                          "measure the overhead, write the Perfetto trace")
     ap.add_argument("--out", default="BENCH_pipeline.json")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     kind = "smoke" if args.smoke else ("small" if args.small else "full")
     res = run(kind, skip_exact=args.skip_exact, trace=args.trace or None)
     res["date"] = time.strftime("%Y-%m-%d")

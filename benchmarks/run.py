@@ -20,6 +20,8 @@ def main(argv=None) -> None:
                     help="comma list: table1,fig5,table3,kernels,serve,"
                          "pipeline,many,service")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     want = set(args.only.split(",")) if args.only else {
         "table1", "fig5", "table3", "kernels", "serve", "pipeline", "many",
         "service"}

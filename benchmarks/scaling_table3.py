@@ -48,8 +48,8 @@ def bsp_cost_model(ps=(4, 8, 16, 32), modes=("neighbor", "grid")):
                                                 layout_step_specs)
             from repro.kernels.grid_force.ops import choose_grid
             from repro.launch.roofline import analyze_text
-            from repro.launch.mesh import make_compat_mesh
-            mesh = make_compat_mesh(({p // 2}, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh = make_mesh(({p // 2}, 2), ("data", "model"))
             n_pad, m_pad, cap = 1 << 18, 1 << 20, 32
             G, cc = choose_grid(n_pad) if "{mode}" == "grid" else (0, 0)
             step, sh = layout_train_step(mesh, n_pad, m_pad, cap,
@@ -67,8 +67,10 @@ def bsp_cost_model(ps=(4, 8, 16, 32), modes=("neighbor", "grid")):
             print(json.dumps(dict(p={p}, mode="{mode}", flops=cost.flops,
                                   bytes=cost.bytes, coll=cost.coll_bytes)))
             """
-            env = dict(os.environ)
-            env["PYTHONPATH"] = os.path.join(REPO, "src")
+            # the child compiles for virtual CPU devices only: keep it off
+            # the chip, which this process may hold
+            env = dict(os.environ, JAX_PLATFORMS="cpu",
+                       PYTHONPATH=os.path.join(REPO, "src"))
             out = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                                  capture_output=True, text=True, env=env,
                                  timeout=600)

@@ -292,6 +292,8 @@ def main(argv=None) -> dict:
                          "(wall-clock-stable; the CI gate)")
     ap.add_argument("--out", default="BENCH_service.json")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     res = run("smoke" if args.smoke else "full")
     res["date"] = time.strftime("%Y-%m-%d")
     res["mode"] = "smoke" if args.smoke else "full"

@@ -76,8 +76,8 @@ def kernel_backend() -> str:
     key: an entry cached under one backend must not be served after the env
     var changes mid-process (tools/gilalint rule R2 enforces this for any
     new cache site)."""
-    from repro.kernels.grid_force.ops import backend_mode
-    return backend_mode()
+    from repro.kernels import backend
+    return backend()
 
 
 # -- per-phase wall-clock accounting ------------------------------------------
@@ -198,7 +198,7 @@ def jit_cache_entries() -> int:
         # dist-engine entries are (jitted_step, shardings) tuples
         fns.append(entry[0] if isinstance(entry, tuple) else entry)
     fns += [_merger.sun_election, _merger.system_growth,
-            _placer._place, gila.gila_forces, gila.gila_layout]
+            _placer._place, gila._gila_forces, gila.gila_layout]
     total = 0
     for f in fns:
         size = getattr(f, "_cache_size", None)

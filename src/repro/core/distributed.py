@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
 def vtx_axes(mesh: Mesh) -> tuple[str, ...]:
@@ -332,9 +332,8 @@ def _grid_rep_spmd(pos_blk, w_blk, C, L, md, *, mesh: Mesh, n_pad: int,
             nbr = ext[n9_c].reshape(-1, K, 3)
             nbr = jnp.pad(nbr, ((0, 0), (0, Kp - K), (0, 0)))
             nbr = jax.lax.dynamic_slice_in_dim(nbr, mi * Kc, Kc, axis=1)
-            return gops.near_field(pos_c[:, None, :], nbr[..., :2],
-                                   nbr[..., 2], C, L, md,
-                                   backend=backend)[:, 0]
+            return gops.near_field(pos_c.T[:, None, :], nbr.T, C, L, md,
+                                   backend=backend)[:, 0].T
     else:
         pos_p = jnp.concatenate(
             [pos_all, jnp.zeros((1, 2), jnp.float32)], 0)
@@ -348,9 +347,10 @@ def _grid_rep_spmd(pos_blk, w_blk, C, L, md, *, mesh: Mesh, n_pad: int,
             pos_c, n9_c = args
             idx = bucket[n9_c].reshape(-1, K)
             idx = jnp.pad(idx, ((0, 0), (0, Kp - K)), constant_values=n_pad)
-            idx = jax.lax.dynamic_slice_in_dim(idx, mi * Kc, Kc, axis=1)
-            return gops.near_field(pos_c[:, None, :], pos_p[idx], w_p[idx],
-                                   C, L, md, backend=backend)[:, 0]
+            idx = jax.lax.dynamic_slice_in_dim(idx, mi * Kc, Kc, axis=1).T
+            nbrs = jnp.concatenate([pos_p.T[:, idx], w_p[idx][None]], 0)
+            return gops.near_field(pos_c.T[:, None, :], nbrs, C, L, md,
+                                   backend=backend)[:, 0].T
 
     f_near = jax.lax.map(near_chunk,
                          (pos_blk.reshape(n_loc // ch, ch, 2),
@@ -379,8 +379,8 @@ def sharded_grid_force(mesh: Mesh, n_pad: int, grid_dim: int, cell_cap: int,
     if variant == "halo":
         assert grid_dim % vsize == 0, (grid_dim, vsize)
     if backend is None:
-        from repro.kernels.grid_force.ops import backend_mode
-        backend = backend_mode()
+        from repro.kernels import backend as kernel_backend
+        backend = kernel_backend()
 
     def local(pos_blk, w_blk, params):
         C, L, md = params[0], params[1], params[2]
@@ -424,8 +424,8 @@ def layout_train_step(mesh: Mesh, n_pad: int, m_pad: int, cap: int,
     msize = mesh.shape["model"]
     if mode == "grid":
         assert grid_dim >= 2 and cell_cap >= 1, (grid_dim, cell_cap)
-        from repro.kernels.grid_force.ops import backend_mode
-        grid_backend = backend_mode()
+        from repro.kernels import backend as kernel_backend
+        grid_backend = kernel_backend()
 
     def repulsion(pos_blk, w_blk, nbr_idx, pos_all, w_all, pos_pad, w_pad,
                   C, L, md):
@@ -561,8 +561,8 @@ def layout_train_step_halo(mesh: Mesh, n_pad: int, m_pad: int, cap: int,
     if mode == "grid":
         assert grid_dim >= 2 and cell_cap >= 1, (grid_dim, cell_cap)
         assert grid_dim % vsize == 0, (grid_dim, vsize)
-        from repro.kernels.grid_force.ops import backend_mode
-        grid_backend = backend_mode()
+        from repro.kernels import backend as kernel_backend
+        grid_backend = kernel_backend()
 
     def local(pos_blk, w_blk, nbr_local, send_idx, src_local, dst_local,
               emask, ewt, params, temp):

@@ -142,13 +142,15 @@ class GilaEngine(RefinementEngine):
         step's ``segment_sum`` scatter — so the float sums stay
         bit-identical while costing ~15× less than a batched scatter.
         Hub-heavy lanes (``inc_k == 0``) fall back to one flat
-        ``segment_sum`` over the fused index space. Dense per-lane math
-        (exact/grid repulsion, cooling clamp) vmaps efficiently and stays
-        vmapped — in grid mode that includes ``bin_vertices``, so spatial
+        ``segment_sum`` over the fused index space. The repulsion of every
+        mode (and the cooling clamp) is vmapped over the same ops the
+        single-graph step calls, so on the chip both run the same Pallas
+        kernels — in grid mode that includes ``bin_vertices``, so spatial
         binning stays per-graph.
         """
         from repro.core import bucketing
         from repro.kernels.nbody import ops as nbody_ops
+        from repro.kernels.neighbor_force import ops as nf_ops
 
         def refine_many(pos0, src, dst, vmask, emask, mass, ewt, nbr_idx,
                         nbr_mask, inc, iters, sparams, params, max_iters):
@@ -156,7 +158,6 @@ class GilaEngine(RefinementEngine):
             m_pad = src.shape[1]
             C, L, md = params[0], params[1], params[2]
             temp_decay = sparams[:, 1]
-            w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)  # [B, n_pad]
             offs = (jnp.arange(B, dtype=jnp.int32) * (n_pad + 1))[:, None]
             flat_dst = (dst + offs).reshape(-1)
             flat_src = src + offs
@@ -200,19 +201,10 @@ class GilaEngine(RefinementEngine):
                                     in_axes=(0, 0, 0, None, None, None))(
                         pos, mass, vmask, C, L, md)
             elif mode == "neighbor":
-                flat_nbr = nbr_idx + offs[:, :, None]            # [B, n_pad, K]
-
                 def repulsion(pos):
-                    flat = flat_pos(pos)
-                    wp = jnp.concatenate(
-                        [w, jnp.zeros((B, 1), w.dtype)], axis=1).reshape(-1)
-                    npos = flat[flat_nbr]                        # [B, n_pad, K, 2]
-                    nw = jnp.where(nbr_mask, wp[flat_nbr], 0.0)
-                    delta = pos[:, :, None, :] - npos
-                    d2 = jnp.sum(delta * delta, axis=-1) + md ** 2
-                    inv = (C * L * L) * nw / d2
-                    f = jnp.sum(delta * inv[..., None], axis=2)
-                    return jnp.where(vmask[..., None], f, 0.0)
+                    return jax.vmap(nf_ops.neighbor_repulsion,
+                                    in_axes=(0, 0, 0, 0, 0, None, None, None))(
+                        pos, mass, nbr_idx, nbr_mask, vmask, C, L, md)
             else:
                 from repro.kernels.grid_force import ops as grid_ops
 

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.graphs.graph import PaddedGraph, edge_gather, to_csr, unique_edges
+from repro.kernels import backend as kernel_backend
 
 
 def paper_k_schedule(m: int) -> int:
@@ -192,13 +193,26 @@ def _attraction(g: PaddedGraph, pos, L, min_dist):
     return out[:n_pad]
 
 
-@partial(jax.jit, static_argnames=("mode", "grid_dim", "cell_cap"))
 def gila_forces(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr,
                 mode: str = "neighbor", grid_dim: int = 0, cell_cap: int = 0):
     """Total force per vertex; ``params_arr = [C, L, min_dist]`` (traced).
 
     ``grid_dim``/``cell_cap`` are the static grid parameters for
     ``mode="grid"`` (pick them with ``kernels.grid_force.choose_grid``)."""
+    return _gila_forces(g, pos, nbr_idx, nbr_mask, params_arr, mode=mode,
+                        grid_dim=grid_dim, cell_cap=cell_cap,
+                        backend=kernel_backend())
+
+
+@partial(jax.jit, static_argnames=("mode", "grid_dim", "cell_cap",
+                                   "backend"))
+def _gila_forces(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr, *,
+                 mode: str, grid_dim: int, cell_cap: int, backend: str):
+    """``gila_forces`` with the kernel backend in its trace-cache key: the
+    trace is reused inside every cached step that calls it, and a step
+    keyed on one ``REPRO_PALLAS`` backend must not inherit a trace of
+    another."""
+    del backend
     C, L, min_dist = params_arr[0], params_arr[1], params_arr[2]
     if mode == "exact":
         rep = _repulsion_exact(pos, g.mass, g.vmask, C, L, min_dist)

@@ -193,8 +193,8 @@ def _layout_one_level(g: PaddedGraph, pos0, sched: LevelSchedule,
                       cfg: LayoutConfig, seed: int):
     if cfg.driver == "multigila_dist":
         from repro.core.distributed import run_layout_level
-        from repro.launch.mesh import make_compat_mesh, make_host_mesh
-        mesh = (make_compat_mesh(tuple(cfg.mesh_shape), ("data", "model"))
+        from repro.launch.mesh import make_mesh, make_host_mesh
+        mesh = (make_mesh(tuple(cfg.mesh_shape), ("data", "model"))
                 if cfg.mesh_shape else make_host_mesh())
         return run_layout_level(mesh, g, pos0, sched,
                                 ideal_len=cfg.ideal_len,

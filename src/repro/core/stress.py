@@ -177,6 +177,7 @@ class StressEngine(engine_mod.RefinementEngine):
         """
         from repro.core import bucketing
         from repro.kernels.nbody import ops as nbody_ops
+        from repro.kernels.neighbor_force import ops as nf_ops
 
         def refine_many(pos0, src, dst, vmask, emask, mass, ewt, nbr_idx,
                         nbr_mask, inc, iters, sparams, params, max_iters):
@@ -184,7 +185,6 @@ class StressEngine(engine_mod.RefinementEngine):
             m_pad = src.shape[1]
             C, L, md = params[0], params[1], params[2]
             temp_decay, alpha_dec = sparams[:, 1], sparams[:, 3]
-            w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)  # [B, n_pad]
             offs = (jnp.arange(B, dtype=jnp.int32) * (n_pad + 1))[:, None]
             flat_dst = (dst + offs).reshape(-1)
             flat_src = src + offs
@@ -233,19 +233,10 @@ class StressEngine(engine_mod.RefinementEngine):
                                     in_axes=(0, 0, 0, 0, None, None))(
                         pos, mass, vmask, ca, L, md)
             elif mode == "neighbor":
-                flat_nbr = nbr_idx + offs[:, :, None]            # [B, n_pad, K]
-
                 def repulsion(pos, ca):
-                    flat = flat_pos(pos)
-                    wp = jnp.concatenate(
-                        [w, jnp.zeros((B, 1), w.dtype)], axis=1).reshape(-1)
-                    npos = flat[flat_nbr]
-                    nw = jnp.where(nbr_mask, wp[flat_nbr], 0.0)
-                    delta = pos[:, :, None, :] - npos
-                    d2 = jnp.sum(delta * delta, axis=-1) + md ** 2
-                    inv = (ca[:, None, None] * L * L) * nw / d2
-                    f = jnp.sum(delta * inv[..., None], axis=2)
-                    return jnp.where(vmask[..., None], f, 0.0)
+                    return jax.vmap(nf_ops.neighbor_repulsion,
+                                    in_axes=(0, 0, 0, 0, 0, 0, None, None))(
+                        pos, mass, nbr_idx, nbr_mask, vmask, ca, L, md)
             else:
                 from repro.kernels.grid_force import ops as grid_ops
 

@@ -13,8 +13,9 @@ Pipeline per call (positions move every iteration, so all of it reruns):
      bucket table [G²+1, cap] (sentinel row/slots = n). Overflow vertices
      keep repelling through the aggregate terms (see 3).
   2. *Near field* (exact): every bucketed vertex vs the buckets of its
-     3×3 cell neighborhood — the Pallas kernel in kernel.py (jnp oracle in
-     ref.py elsewhere).
+     3×3 cell neighborhood — the grouped Pallas kernel of
+     kernels/neighbor_force, one group per cell (jnp oracle in ref.py
+     elsewhere).
   3. *Far field* (approximate): every vertex vs per-cell aggregates
      (total mass at centroid) of ALL cells, minus the same aggregate field
      of its 9 near cells (those were counted exactly), plus the
@@ -25,15 +26,16 @@ Pipeline per call (positions move every iteration, so all of it reruns):
      in-bucket aggregates of their 9 near cells (they have no bucket row,
      so the exact kernel never sees them). With no overflow this is the
      textbook flat Barnes–Hut with opening radius one cell; with overflow
-     it degrades gracefully instead of dropping mass.
+     it degrades gracefully instead of dropping mass. The all-cells term
+     is the all-pairs Pallas kernel of kernels/nbody with the cells as
+     sources.
 
 Approximation error: far cells are ≥ 1 cell width away, so the opening
 angle is ≤ 1 and the centroid approximation of the 1/d force field is
 accurate to a few percent; tests/test_grid_force.py bounds it end-to-end
 against the all-pairs oracle on random and clustered inputs.
 
-Set ``REPRO_PALLAS=interpret|ref|pallas`` to force a backend (same
-convention as the other kernel subsystems).
+``repro.kernels.backend`` picks the kernel backend (``REPRO_PALLAS``).
 
 The helpers here are also the building blocks of the *sharded* grid path
 (`core/distributed.py:sharded_grid_force`, DESIGN.md §4.3): binning and the
@@ -44,24 +46,18 @@ and ``near_field`` resolves the 3×3 near field per shard.
 from __future__ import annotations
 
 import math
-import os
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
-from repro.kernels.grid_force.kernel import grid_near_pallas, grid_far_pallas
+from repro.kernels import backend as kernel_backend, round_up
 from repro.kernels.grid_force.ref import grid_near_ref, grid_far_ref
+from repro.kernels.nbody.kernel import nbody_pallas
+from repro.kernels.neighbor_force.kernel import neighbor_pallas
 
 _EPS = 1e-12
-
-
-def _mode() -> str:
-    env = os.environ.get("REPRO_PALLAS", "auto")
-    if env in ("interpret", "ref", "pallas"):
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
 def choose_grid(n: int, *, avg_occupancy: int = 12,
@@ -174,43 +170,37 @@ def _agg_field_9(pos, mu9, m9, C, L, md, r9=None):
                       jnp.sum(dy * inv, axis=1)], axis=1)
 
 
-def _round_up(x: int, mult: int) -> int:
-    return ((x + mult - 1) // mult) * mult
-
-
 def _far_all_cells(pos, cell_xyw, C, L, md, mode: str):
-    """Aggregate field of ALL cells on every vertex (backend-dispatched)."""
-    n, nc = pos.shape[0], cell_xyw.shape[0]
+    """Aggregate field of ALL cells on every vertex (backend-dispatched):
+    pos [n, 2] vs cell_xyw [nc, 3] (x, y, mass) → [n, 2]."""
+    n = pos.shape[0]
     if mode == "ref":
         chunk = 512
-        npad = _round_up(n, chunk)
+        npad = round_up(n, chunk)
         pp = jnp.pad(pos, ((0, npad - n), (0, 0)))
         out = jax.lax.map(
             lambda blk: grid_far_ref(blk, cell_xyw, C, L, md),
             pp.reshape(npad // chunk, chunk, 2))
         return out.reshape(npad, 2)[:n]
-    npad, ncpad = _round_up(n, 128), _round_up(nc, 128)
-    pp = jnp.pad(pos, ((0, npad - n), (0, 0)))
-    cp = jnp.pad(cell_xyw, ((0, ncpad - nc), (0, 0)))   # padded cells: w = 0
-    out = grid_far_pallas(pp, cp, C, L, md, block_rows=128, block_cols=128,
-                          interpret=(mode == "interpret"))
-    return out[:n]
+    return nbody_pallas(pos.T, cell_xyw.T, C, L, md,
+                        interpret=(mode == "interpret")).T
 
 
-def near_field(rows_pos, nbr_pos, nbr_w, C, L, min_dist, *,
-               backend: str | None = None, block_cells: int = 1):
-    """Backend-dispatched near-field evaluation (kernel.py vs ref.py).
+def near_field(rows, nbrs, C, L, min_dist, *, backend: str | None = None):
+    """Backend-dispatched near-field evaluation over lane-major planes:
+    rows [2, cap, R] (x, y) vs each row group's partners nbrs [3, K, R]
+    (x, y, weight; 0 = masked) → forces [2, cap, R].
 
-    rows_pos [R, cap, 2] vs nbr_pos/nbr_w [R, K, 2]/[R, K] → [R, cap, 2].
-    The sharded path calls this per shard with cap = 1 (one row per local
-    vertex); the single-device path with cap = cell_cap (one row per cell).
-    """
-    backend = backend or _mode()
+    The sharded path calls this per shard with cap = 1 (one group per
+    local vertex); the single-device path with cap = cell_cap (one group
+    per cell)."""
+    backend = backend or kernel_backend()
     if backend == "ref":
-        return grid_near_ref(rows_pos, nbr_pos, nbr_w, C, L, min_dist)
-    return grid_near_pallas(rows_pos, nbr_pos, nbr_w, C, L, min_dist,
-                            block_cells=block_cells,
-                            interpret=(backend == "interpret"))
+        t = lambda a: jnp.transpose(a, (2, 1, 0))
+        return t(grid_near_ref(t(rows), t(nbrs[:2]), nbrs[2].T,
+                               C, L, min_dist))
+    return neighbor_pallas(rows, nbrs, C, L, min_dist,
+                           interpret=(backend == "interpret"))
 
 
 def cell_centers_from_box(lo, hi, grid_dim: int):
@@ -303,7 +293,7 @@ def grid_repulsion(pos, mass, vmask, C, L, min_dist, *,
     work is traced, so the op rebins on every call.
     """
     assert grid_dim >= 2 and cell_cap >= 1, (grid_dim, cell_cap)
-    mode = _mode()
+    mode = kernel_backend()
     n = pos.shape[0]
     G, cap = grid_dim, cell_cap
     nc = G * G
@@ -322,17 +312,17 @@ def grid_repulsion(pos, mass, vmask, C, L, min_dist, *,
     Q_out = jax.ops.segment_sum(w_out * q, cid, num_segments=nc + 1)
 
     # -- near field: exact within the 3×3 neighborhood ------------------------
+    # gathered straight into the kernel's lane-major planes (cells on lanes)
     table = jnp.asarray(_neighbor_table(G))                 # [nc+1, 9]
-    pos_p = jnp.concatenate([pos, jnp.zeros((1, 2), jnp.float32)], axis=0)
-    w_p = jnp.concatenate([w, jnp.zeros((1,), jnp.float32)], axis=0)
+    xyw_p = jnp.pad(jnp.concatenate([pos.T, w[None]], axis=0),
+                    ((0, 0), (0, 1)))                       # [3, n+1]
     rows_idx = bucket[:nc]                                  # [nc, cap]
-    rows_pos = pos_p[rows_idx]
     nbr_bucket = bucket[table[:nc]].reshape(nc, 9 * cap)
-    nbr_pos = pos_p[nbr_bucket]
-    nbr_w = w_p[nbr_bucket]
-    near = near_field(rows_pos, nbr_pos, nbr_w, C, L, min_dist, backend=mode)
+    near = near_field(xyw_p[:2, rows_idx.T], xyw_p[:, nbr_bucket.T],
+                      C, L, min_dist, backend=mode)         # [2, cap, nc]
     f_near = jnp.zeros((n + 1, 2), jnp.float32).at[
-        rows_idx.reshape(-1)].set(near.reshape(-1, 2))[:n]
+        rows_idx.reshape(-1)].set(
+        jnp.transpose(near, (2, 1, 0)).reshape(-1, 2))[:n]
 
     # -- far field: all-cell aggregates, near cells swapped for overflow ------
     cell_xyw = jnp.concatenate([mu_full[:nc], M_full[:nc, None]], axis=1)
@@ -349,4 +339,3 @@ neighbor_table = _neighbor_table
 cell_aggregates = _cell_aggregates
 agg_field_9 = _agg_field_9
 far_all_cells = _far_all_cells
-backend_mode = _mode

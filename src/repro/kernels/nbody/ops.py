@@ -1,34 +1,25 @@
-"""Jit'd wrapper + platform dispatch for the N-body repulsion kernel.
+"""Platform dispatch for the all-pairs N-body repulsion.
 
-On TPU the Pallas kernel runs natively; elsewhere the pure-jnp reference
-executes (XLA fuses it well on CPU). Set ``REPRO_PALLAS=interpret`` to force
-the Pallas kernel through the interpreter (used by integration tests).
+On the chip the Pallas kernel runs compiled; elsewhere the pure-jnp
+reference executes (XLA fuses it well on CPU). ``REPRO_PALLAS=interpret``
+forces the Pallas kernel through the interpreter (integration tests); see
+``repro.kernels.backend``. Any n works: the kernel pads to its blocks.
 """
 from __future__ import annotations
 
-import os
+import jax.numpy as jnp
 
-import jax
-
-from repro.kernels.nbody.kernel import nbody_repulsion_pallas
+from repro.kernels import backend
+from repro.kernels.nbody.kernel import nbody_pallas
 from repro.kernels.nbody.ref import nbody_repulsion_ref
 
 
-def _mode() -> str:
-    env = os.environ.get("REPRO_PALLAS", "auto")
-    if env in ("interpret", "ref", "pallas"):
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
 def nbody_repulsion(pos, mass, vmask, C, L, min_dist):
-    mode = _mode()
+    mode = backend()
     if mode == "ref":
         return nbody_repulsion_ref(pos, mass, vmask, C, L, min_dist)
-    n = pos.shape[0]
-    block = 256 if n % 256 == 0 else (128 if n % 128 == 0 else None)
-    if block is None:  # unaligned shapes fall back to the oracle
-        return nbody_repulsion_ref(pos, mass, vmask, C, L, min_dist)
-    return nbody_repulsion_pallas(pos, mass, vmask, C, L, min_dist,
-                                  block_rows=block, block_cols=block,
-                                  interpret=(mode == "interpret"))
+    w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)
+    rows = pos.astype(jnp.float32).T                     # [2, n]
+    f = nbody_pallas(rows, jnp.concatenate([rows, w[None]], axis=0),
+                     C, L, min_dist, interpret=(mode == "interpret"))
+    return jnp.where(vmask[:, None], f.T, 0.0)

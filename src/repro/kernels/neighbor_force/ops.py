@@ -1,39 +1,30 @@
-"""Jit'd wrapper + dispatch for the neighbor-list repulsion kernel."""
+"""Platform dispatch for the neighbor-list repulsion.
+
+The irregular gather of neighbor positions happens in XLA, straight into
+the kernel's lane-major partner planes ``[3, K, n]`` (x, y, weight); the
+sentinel index n reads a zero row, so masked slots contribute nothing.
+"""
 from __future__ import annotations
 
-import os
-
-import jax
 import jax.numpy as jnp
 
-from repro.kernels.neighbor_force.kernel import neighbor_repulsion_pallas
+from repro.kernels import backend
+from repro.kernels.neighbor_force.kernel import neighbor_pallas
 from repro.kernels.neighbor_force.ref import neighbor_repulsion_ref
 
 
-def _mode() -> str:
-    env = os.environ.get("REPRO_PALLAS", "auto")
-    if env in ("interpret", "ref", "pallas"):
-        return env
-    return "pallas" if jax.default_backend() == "tpu" else "ref"
-
-
 def neighbor_repulsion(pos, mass, nbr_idx, nbr_mask, vmask, C, L, min_dist):
-    mode = _mode()
+    mode = backend()
     if mode == "ref":
         return neighbor_repulsion_ref(pos, mass, nbr_idx, nbr_mask, vmask,
                                       C, L, min_dist)
-    # XLA-side gather (padded tables make the sentinel row contribute 0)
     w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)
-    pos_p = jnp.concatenate([pos, jnp.zeros((1, 2), pos.dtype)], axis=0)
-    w_p = jnp.concatenate([w, jnp.zeros((1,), w.dtype)], axis=0)
-    nbr_pos = pos_p[nbr_idx]
-    nbr_w = jnp.where(nbr_mask, w_p[nbr_idx], 0.0)
-    n = pos.shape[0]
-    block = 128 if n % 128 == 0 else None
-    if block is None:
-        return neighbor_repulsion_ref(pos, mass, nbr_idx, nbr_mask, vmask,
-                                      C, L, min_dist)
-    f = neighbor_repulsion_pallas(pos, nbr_pos, nbr_w, C, L, min_dist,
-                                  block_rows=block,
-                                  interpret=(mode == "interpret"))
-    return jnp.where(vmask[:, None], f, 0.0)
+    rows = pos.astype(jnp.float32).T                     # [2, n]
+    xy_p = jnp.pad(rows, ((0, 0), (0, 1)))               # sentinel column n
+    w_p = jnp.pad(w, (0, 1))
+    idx = nbr_idx.T                                      # [K, n]
+    nbrs = jnp.stack([xy_p[0][idx], xy_p[1][idx],
+                      jnp.where(nbr_mask.T, w_p[idx], 0.0)])
+    f = neighbor_pallas(rows[:, None, :], nbrs, C, L, min_dist,
+                        interpret=(mode == "interpret"))[:, 0]
+    return jnp.where(vmask[:, None], f.T, 0.0)

@@ -30,7 +30,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, list_archs, cells_for, SHAPES
 from repro.configs.base import ArchConfig, ShapeCell
-from repro.launch.mesh import make_production_mesh, PEAK_FLOPS_BF16
+from repro.launch.mesh import make_production_mesh, peaks
 from repro.launch import roofline as RL
 from repro.models import model as M
 from repro.models.model import param_specs, input_specs
@@ -261,10 +261,10 @@ def analyze(compiled, cfg, cell, mesh, compile_s, opts):
     peak_bytes = int(ma.argument_size_in_bytes + ma.temp_size_in_bytes
                      + ma.output_size_in_bytes - ma.alias_size_in_bytes)
 
-    from repro.launch.mesh import PEAK_FLOPS_BF16, HBM_BW, ICI_BW
-    compute_s = cost.flops / PEAK_FLOPS_BF16
-    memory_s = an["bytes"] / HBM_BW
-    coll_s = cost.coll_bytes / ICI_BW
+    pk = peaks()
+    compute_s = cost.flops / pk["flops_bf16"]
+    memory_s = an["bytes"] / pk["hbm_bw"]
+    coll_s = cost.coll_bytes / pk["ici_bw"]
     total = max(compute_s, memory_s, coll_s)
     bottleneck = {compute_s: "compute", memory_s: "memory",
                   coll_s: "collective"}[total]
@@ -275,7 +275,7 @@ def analyze(compiled, cfg, cell, mesh, compile_s, opts):
     if cell.kind == "decode":
         frac = memory_s / total if total > 0 else 0.0
     else:
-        frac = (mf / PEAK_FLOPS_BF16 / total) if total > 0 else 0.0
+        frac = (mf / pk["flops_bf16"] / total) if total > 0 else 0.0
     terms = {
         "compute_s": compute_s, "memory_s": memory_s,
         "collective_s": coll_s, "bottleneck": bottleneck,

@@ -30,6 +30,7 @@ from repro.graphs.metrics import quality_report
 from repro.graphs.graph import build_graph
 from repro.graphs.io import save_svg
 from repro.core import multigila_layout, multigila_layout_many, LayoutConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
@@ -62,6 +63,7 @@ def main(argv=None):
     ap.add_argument("--trace", default="", metavar="OUT.json",
                     help="write a Chrome/Perfetto trace of the run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         from repro.obs import trace as obs_trace

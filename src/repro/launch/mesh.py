@@ -1,47 +1,54 @@
-"""Production mesh construction.
+"""Mesh construction and the per-chip peak rates.
 
-A function (not a module-level constant) so importing never touches jax
+Functions (not module-level constants) so importing never touches jax
 device state; dryrun.py sets XLA_FLAGS for 512 placeholder devices BEFORE
-importing jax and then calls this.
+importing jax and then calls these.
 """
 from __future__ import annotations
-
-import numpy as np
 
 import jax
 
 
-def make_compat_mesh(shape, axes):
-    """Version-portable mesh constructor.
-
-    `jax.sharding.AxisType` and `jax.make_mesh(axis_types=...)` only exist on
-    newer JAX; on 0.4.x every mesh axis is implicitly Auto, so plain
-    `jax.make_mesh` (or `Mesh` on even older versions) is equivalent.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes)
-    devices = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return jax.sharding.Mesh(devices, axes)
+def make_mesh(shape, axes):
+    """A mesh over the first prod(shape) devices with every axis ``Auto``:
+    the layout code shards through ``shard_map`` and ``NamedSharding``, not
+    through sharding-in-types (``jax.make_mesh`` now defaults to
+    ``Explicit``)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_compat_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1):
     """Mesh over whatever devices exist (CPU tests / local runs)."""
     n = jax.device_count()
     assert n % model == 0
-    return make_compat_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
-# TPU v5e hardware constants used by the roofline (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW = 50e9                   # B/s per link direction
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM
+# at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect over four links
+# (50 GB/s per link and direction).
+PEAKS = {
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9,
+                    "hbm_bytes": 16e9},
+}
+
+#: the chip the production mesh and the dry-run roofline are sized for
+TARGET_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str = TARGET_KIND) -> dict:
+    """Peak rates of one chip of ``device_kind``; an unknown kind is an
+    error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None

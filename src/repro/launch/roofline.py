@@ -287,13 +287,14 @@ def analyze_text(text: str, world: int = 1, *, force_trip_one: bool = False):
 
 # -- roofline terms ----------------------------------------------------------------
 
-from repro.launch.mesh import PEAK_FLOPS_BF16, HBM_BW, ICI_BW
+from repro.launch.mesh import peaks
 
 
 def roofline_terms(cost: Cost, *, model_flops_per_device: float = 0.0):
-    compute_s = cost.flops / PEAK_FLOPS_BF16
-    memory_s = cost.bytes / HBM_BW
-    coll_s = cost.coll_bytes / ICI_BW
+    pk = peaks()
+    compute_s = cost.flops / pk["flops_bf16"]
+    memory_s = cost.bytes / pk["hbm_bw"]
+    coll_s = cost.coll_bytes / pk["ici_bw"]
     dom = max((compute_s, "compute"), (memory_s, "memory"),
               (coll_s, "collective"))
     total = max(compute_s, memory_s, coll_s)
@@ -308,7 +309,7 @@ def roofline_terms(cost: Cost, *, model_flops_per_device: float = 0.0):
         "model_flops": model_flops_per_device,
         "useful_ratio": (model_flops_per_device / cost.flops
                          if cost.flops else 0.0),
-        "roofline_frac": (model_flops_per_device / PEAK_FLOPS_BF16 / total
+        "roofline_frac": (model_flops_per_device / pk["flops_bf16"] / total
                           if total > 0 else 0.0),
     }
 

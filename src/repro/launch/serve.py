@@ -32,6 +32,7 @@ from repro.graphs.io import load_edgelist
 from repro.serve import (build_pyramid, save_pyramid, load_pyramid,
                          QueryEngine, MicroBatcher)
 from repro.serve.query import random_viewports
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def _load_graph(args):
@@ -156,6 +157,7 @@ def main(argv=None):
                     help="closed-loop requests per batch size")
     ap.add_argument("--json", default="results/serve/bench.json")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.build:
         return build(args)

@@ -190,6 +190,8 @@ def main(argv=None) -> None:
                     help="serve 3 graphs over HTTP on an ephemeral port, "
                          "assert parity, exit")
     args = ap.parse_args(argv)
+    from repro.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.smoke:
         smoke()
         return

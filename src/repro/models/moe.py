@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.parallel.sharding import shard, current_rules
-from repro.utils.compat import shard_map
+from jax import shard_map
 from repro.models.layers import _normal
 
 

@@ -17,7 +17,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from repro.utils.compat import shard_map, pvary
+from jax import shard_map
 
 
 def quantize_int8(x: jnp.ndarray):
@@ -92,7 +92,7 @@ def ring_collective_matmul(mesh: Mesh, axis: str = "model"):
         acc0 = jnp.zeros((size, S_loc, w_blk.shape[1]), x_blk.dtype)
         # the carry becomes device-varying inside the loop (ppermute);
         # mark the initial zeros accordingly (shard_map vma rules)
-        acc0 = pvary(acc0, (axis,))
+        acc0 = jax.lax.pcast(acc0, (axis,), to="varying")
         acc, _ = jax.lax.fori_loop(0, size, body, (acc0, x_blk))
         return acc.reshape(size * S_loc, w_blk.shape[1])
 

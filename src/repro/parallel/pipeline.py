@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.compat import shard_map, pvary
+from jax import shard_map
 
 
 def pipeline_scan(mesh: Mesh, stage_fn, n_microbatches: int):
@@ -57,7 +57,8 @@ def pipeline_scan(mesh: Mesh, stage_fn, n_microbatches: int):
             y = stage_fn(params_local, x_in)
             return y, y                         # stack every tick's output
 
-        y0 = pvary(jnp.zeros(mb_shape, x_mb.dtype), ("pod",))
+        y0 = jax.lax.pcast(jnp.zeros(mb_shape, x_mb.dtype), ("pod",),
+                           to="varying")
         _, ys_all = jax.lax.scan(tick, y0, jnp.arange(M + S_stages - 1))
         # microbatch m finishes on the LAST stage at tick m + S − 1:
         # a STATIC slice of the stacked outputs (bubble ticks fall outside)

@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.utils.compat import shard_map, pvary
+from jax import shard_map
 
 
 def ring_attention(mesh: Mesh, *, axis: str = "model", causal: bool = True,
@@ -65,7 +65,8 @@ def ring_attention(mesh: Mesh, *, axis: str = "model", causal: bool = True,
         m0 = jnp.full((B, KV, G, Sq), -jnp.inf, jnp.float32)
         l0 = jnp.zeros((B, KV, G, Sq), jnp.float32)
         a0 = jnp.zeros((B, KV, G, Sq, hd), v.dtype)
-        m0, l0, a0 = (pvary(x, (axis,)) for x in (m0, l0, a0))
+        m0, l0, a0 = (jax.lax.pcast(x, (axis,), to="varying")
+                      for x in (m0, l0, a0))
         (m, l, acc, _, _), _ = jax.lax.scan(
             step, (m0, l0, a0, k, v), jnp.arange(size))
         out = acc / jnp.maximum(l, 1e-30)[..., None].astype(acc.dtype)

@@ -17,7 +17,7 @@ def run_sub(code: str, extra_env: dict | None = None) -> str:
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     env.update(extra_env or {})
     # every snippet builds meshes through the version-portable constructor
-    prelude = "from repro.launch.mesh import make_compat_mesh\n"
+    prelude = "from repro.launch.mesh import make_mesh\n"
     out = subprocess.run([sys.executable, "-c",
                           prelude + textwrap.dedent(code)],
                          capture_output=True, text=True, env=env, timeout=600)
@@ -32,7 +32,7 @@ def test_sharded_nbody_matches_reference():
         from repro.graphs import generators as G
         from repro.graphs.graph import build_graph
         from repro.kernels.nbody.ref import nbody_repulsion_ref
-        mesh = make_compat_mesh((4,2), ("data","model"))
+        mesh = make_mesh((4,2), ("data","model"))
         n_pad = 256
         e, n = G.grid(12, 12)
         g = build_graph(e, n, n_pad=n_pad)
@@ -64,7 +64,7 @@ def test_sharded_train_step_matches_single_device():
         batch = {"tokens": jnp.asarray(rng.integers(0, cfg.vocab, (4, 64)), jnp.int32),
                  "labels": jnp.asarray(rng.integers(0, cfg.vocab, (4, 64)), jnp.int32)}
         l0, _ = jax.jit(lambda p,b: loss_fn(p, cfg, b))(params, batch)
-        mesh = make_compat_mesh((4,2), ("data","model"))
+        mesh = make_mesh((4,2), ("data","model"))
         rules = make_rules(mesh, cfg)
         with use_shardings(mesh, rules):
             sh = param_shardings(mesh, rules, param_specs(cfg, rules))
@@ -81,7 +81,7 @@ def test_ring_collective_matmul_matches_allgather():
     out = run_sub("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.parallel.collectives import ring_collective_matmul
-        mesh = make_compat_mesh((1,8), ("data","model"))
+        mesh = make_mesh((1,8), ("data","model"))
         S, K, N = 64, 32, 48
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(S,K)), jnp.float32)
@@ -149,7 +149,7 @@ def test_shardmap_moe_matches_gspmd():
         from repro.models import moe as MOE
         from repro.configs.base import MoEConfig
         from repro.parallel.sharding import make_rules, use_shardings
-        mesh = make_compat_mesh((2,4), ("data","model"))
+        mesh = make_mesh((2,4), ("data","model"))
         m = MoEConfig(n_experts=8, top_k=2, d_expert=16, capacity_factor=2.0)
         p = MOE.init_moe(jax.random.PRNGKey(0), 32, m)
         x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 32), jnp.float32)
@@ -173,7 +173,7 @@ def test_a2a_moe_matches_reference():
         from repro.models import moe as MOE
         from repro.configs.base import MoEConfig
         from repro.parallel.sharding import make_rules, use_shardings
-        mesh = make_compat_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         m = MoEConfig(n_experts=8, top_k=2, d_expert=16, capacity_factor=4.0)
         p = MOE.init_moe(jax.random.PRNGKey(0), 32, m)
         x = jax.random.normal(jax.random.PRNGKey(1), (8, 32, 32), jnp.float32)
@@ -199,7 +199,7 @@ def test_sharded_grid_force_matches_single_device():
         from repro.core import distributed as D
         from repro.kernels.grid_force.ops import (grid_repulsion, choose_grid,
                                                   bin_vertices)
-        mesh = make_compat_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n_pad = 512
         rng = np.random.default_rng(0)
         params = jnp.asarray([1.2, 0.9, 1e-2], jnp.float32)
@@ -240,7 +240,7 @@ def test_sharded_grid_force_halo_matches_under_band_partition():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import distributed as D
         from repro.kernels.grid_force.ops import grid_repulsion, bin_vertices
-        mesh = make_compat_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n_pad, vsize = 512, 4
         n_loc = n_pad // vsize
         G, cap = 8, 16                       # G % vsize == 0 (band contract)
@@ -295,7 +295,7 @@ def test_layout_grid_step_lowers_and_matches():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core.distributed import layout_train_step, layout_step_specs
         from repro.kernels.grid_force.ops import grid_repulsion, choose_grid
-        mesh = make_compat_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n_pad, m_pad = 512, 64
         G, cap = choose_grid(n_pad)
         rng = np.random.default_rng(3)
@@ -392,7 +392,7 @@ def test_layout_halo_step_runs():
         import numpy as np, jax, jax.numpy as jnp
         from repro.core.distributed import (layout_train_step,
                                             layout_train_step_halo)
-        mesh = make_compat_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         n_pad, cap = 64, 8
         vsize, n_loc = 4, 16
         halo = n_loc                     # full halo → exactly the AG step
@@ -442,7 +442,7 @@ def test_pipeline_parallel_matches_reference():
         from repro.models import init_params, forward
         from repro.parallel.pipeline import pipeline_forward
         from repro.parallel.sharding import make_rules, use_shardings
-        mesh = make_compat_mesh((2, 2, 2), ("pod", "data", "model"))
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         cfg = get_smoke_config("internlm2-1.8b")
         params = init_params(cfg, jax.random.PRNGKey(0))
         rng = np.random.default_rng(0)
@@ -477,7 +477,7 @@ def test_ring_attention_matches_sdpa():
         import numpy as np, jax, jax.numpy as jnp
         from repro.parallel.ring_attention import ring_attention
         from repro.models.layers import _sdpa
-        mesh = make_compat_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         rng = np.random.default_rng(0)
         B, S, H, KV, hd = 2, 256, 4, 2, 32
         for dtype, tol in ((jnp.float32, 2e-5), (jnp.bfloat16, 3e-2)):
@@ -506,7 +506,7 @@ def test_small_mesh_dryrun_decode():
         from repro.models import model as M
         from repro.parallel.sharding import make_rules, use_shardings
         cfg = get_smoke_config("gemma-2b")
-        mesh = make_compat_mesh((4,2), ("data","model"))
+        mesh = make_mesh((4,2), ("data","model"))
         rules = make_rules(mesh, cfg)
         B, cache = 8, 256
         params_struct = jax.eval_shape(partial(M.init_params, cfg),

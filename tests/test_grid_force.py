@@ -4,8 +4,9 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.kernels.grid_force.kernel import grid_near_pallas, grid_far_pallas
 from repro.kernels.grid_force.ref import grid_near_ref, grid_far_ref
+from repro.kernels.nbody.kernel import nbody_pallas
+from repro.kernels.neighbor_force.kernel import neighbor_pallas
 from repro.kernels.grid_force.ops import grid_repulsion, choose_grid
 from repro.kernels.nbody.ref import nbody_repulsion_ref
 
@@ -18,9 +19,12 @@ def test_grid_near_kernel_matches_ref(nc, cap, block):
     npos = rng.random((nc, 9 * cap, 2)).astype(np.float32) * 4
     nw = np.where(rng.random((nc, 9 * cap)) > 0.3,
                   rng.random((nc, 9 * cap)) + 0.5, 0.0).astype(np.float32)
-    out = grid_near_pallas(jnp.asarray(rows), jnp.asarray(npos),
-                           jnp.asarray(nw), 1.3, 0.8, 1e-2,
-                           block_cells=block, interpret=True)
+    # lane-major planes, one group per cell; ``block`` lanes of 128 cells
+    t = lambda a: np.transpose(a, (2, 1, 0))
+    planes = t(np.concatenate([npos, nw[..., None]], axis=2))
+    out = t(np.asarray(neighbor_pallas(
+        jnp.asarray(t(rows)), jnp.asarray(planes), 1.3, 0.8, 1e-2,
+        block_cols=128 * block, interpret=True)))
     ref = grid_near_ref(jnp.asarray(rows), jnp.asarray(npos),
                         jnp.asarray(nw), 1.3, 0.8, 1e-2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -35,8 +39,9 @@ def test_grid_far_kernel_matches_ref(n, nc, br, bc):
     cells = np.concatenate(
         [rng.random((nc, 2)).astype(np.float32) * 10,
          (rng.random((nc, 1)) * 20).astype(np.float32)], axis=1)
-    out = grid_far_pallas(jnp.asarray(pos), jnp.asarray(cells), 1.1, 0.9,
-                          1e-2, block_rows=br, block_cols=bc, interpret=True)
+    out = nbody_pallas(jnp.asarray(pos.T), jnp.asarray(cells.T), 1.1, 0.9,
+                       1e-2, block_rows=br, block_cols=bc,
+                       interpret=True).T
     ref = grid_far_ref(jnp.asarray(pos), jnp.asarray(cells), 1.1, 0.9, 1e-2)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-4)

@@ -3,9 +3,9 @@ import numpy as np
 import pytest
 import jax.numpy as jnp
 
-from repro.kernels.nbody.kernel import nbody_repulsion_pallas
+from repro.kernels.nbody.kernel import nbody_pallas
 from repro.kernels.nbody.ref import nbody_repulsion_ref
-from repro.kernels.neighbor_force.kernel import neighbor_repulsion_pallas
+from repro.kernels.neighbor_force.kernel import neighbor_pallas
 from repro.kernels.neighbor_force.ref import neighbor_repulsion_ref
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.flash_attention.ref import flash_attention_ref
@@ -18,12 +18,13 @@ def test_nbody_kernel_sweep(n, block, dtype):
     pos = jnp.asarray(rng.random((n, 2)) * 10, dtype)
     mass = jnp.asarray(rng.random(n) + 0.5, dtype)
     vmask = jnp.asarray(rng.random(n) > 0.15)
-    out = nbody_repulsion_pallas(pos, mass, vmask, 1.3, 0.8, 1e-2,
-                                 block_rows=block, block_cols=block,
-                                 interpret=True)
+    w = jnp.where(vmask, mass, 0.0)
+    out = nbody_pallas(pos.T, jnp.concatenate([pos.T, w[None]]),
+                       1.3, 0.8, 1e-2, block_rows=block, block_cols=block,
+                       interpret=True).T
     ref = nbody_repulsion_ref(pos, mass, vmask, 1.3, 0.8, 1e-2)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(out) * np.asarray(vmask)[:, None],
+                               np.asarray(ref), rtol=2e-4, atol=2e-4)
 
 
 @pytest.mark.parametrize("n,K,block", [(128, 8, 128), (256, 32, 128),
@@ -40,14 +41,59 @@ def test_neighbor_kernel_sweep(n, K, block):
     w_p = np.concatenate([w, np.zeros(1, np.float32)])
     npos = pos_p[nbr]
     nw = np.where(nmask, w_p[nbr], 0).astype(np.float32)
-    out = neighbor_repulsion_pallas(jnp.asarray(pos), jnp.asarray(npos),
-                                    jnp.asarray(nw), 1.1, 0.9, 1e-2,
-                                    block_rows=block, interpret=True)
+    nbrs = np.stack([npos[..., 0].T, npos[..., 1].T, nw.T])   # [3, K, n]
+    out = neighbor_pallas(jnp.asarray(pos.T[:, None, :]), jnp.asarray(nbrs),
+                          1.1, 0.9, 1e-2, block_cols=block,
+                          interpret=True)[:, 0].T
     ref = neighbor_repulsion_ref(jnp.asarray(pos), jnp.asarray(mass),
                                  jnp.asarray(nbr), jnp.asarray(nmask),
                                  jnp.asarray(vmask), 1.1, 0.9, 1e-2)
     np.testing.assert_allclose(np.asarray(out) * vmask[:, None],
                                np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("op", ["nbody", "neighbor"])
+def test_unaligned_n_pads_and_runs_pallas(op, monkeypatch):
+    """An n that is no multiple of any block (300) pads to the kernel's
+    blocks and runs the Pallas kernel — there is no oracle fallback."""
+    from repro.kernels import backend
+    from repro.kernels.nbody import ops as nbody_ops
+    from repro.kernels.neighbor_force import ops as nf_ops
+    monkeypatch.setenv("REPRO_PALLAS", "interpret")
+    assert backend() == "interpret"
+    n, K = 300, 24
+    rng = np.random.default_rng(n)
+    pos = jnp.asarray(rng.random((n, 2)) * 6, jnp.float32)
+    mass = jnp.asarray(rng.random(n) + 0.5, jnp.float32)
+    vmask = jnp.asarray(rng.random(n) > 0.1)
+    calls = []
+    if op == "nbody":
+        monkeypatch.setattr(nbody_ops, "nbody_pallas",
+                            lambda *a, **k: calls.append(1)
+                            or nbody_pallas(*a, **k))
+        out = nbody_ops.nbody_repulsion(pos, mass, vmask, 1.2, 0.9, 1e-2)
+        ref = nbody_repulsion_ref(pos, mass, vmask, 1.2, 0.9, 1e-2)
+    else:
+        nbr = jnp.asarray(rng.integers(0, n + 1, (n, K)), jnp.int32)
+        nmask = jnp.asarray(rng.random((n, K)) > 0.2)
+        monkeypatch.setattr(nf_ops, "neighbor_pallas",
+                            lambda *a, **k: calls.append(1)
+                            or neighbor_pallas(*a, **k))
+        out = nf_ops.neighbor_repulsion(pos, mass, nbr, nmask, vmask,
+                                        1.2, 0.9, 1e-2)
+        ref = neighbor_repulsion_ref(pos, mass, nbr, nmask, vmask,
+                                     1.2, 0.9, 1e-2)
+    assert calls, "the Pallas kernel did not run"
+    assert out.shape == (n, 2)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-4)
+
+
+def test_unknown_kernel_backend_is_an_error(monkeypatch):
+    from repro.kernels import backend
+    monkeypatch.setenv("REPRO_PALLAS", "palas")
+    with pytest.raises(ValueError, match="REPRO_PALLAS"):
+        backend()
 
 
 @pytest.mark.parametrize("B,Sq,Sk,hd,bq,bk", [

@@ -82,3 +82,13 @@ def test_roofline_terms_bottleneck():
     assert t["roofline_frac"] == pytest.approx(1.0)
     t = roofline_terms(Cost(flops=1.0, bytes=819e9 * 2, coll_bytes=0.0))
     assert t["bottleneck"] == "memory"
+
+
+def test_peaks_keyed_by_device_kind():
+    """Peak rates come from the table keyed by ``device_kind`` (TPU v5e,
+    Google Cloud's published figures); an unknown chip is an error."""
+    from repro.launch.mesh import TARGET_KIND, peaks
+    pk = peaks(TARGET_KIND)
+    assert pk["flops_bf16"] == 197e12 and pk["hbm_bw"] == 819e9
+    with pytest.raises(ValueError, match="no published peaks"):
+        peaks("cpu")

@@ -1,7 +1,7 @@
 """R5 positives: shard_map arity mismatch + undeclared mesh axis."""
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
 def local(pos, w, params):

@@ -1,7 +1,7 @@
 """R5 negative: one spec per parameter, declared axes only."""
 from jax.sharding import PartitionSpec as P
 
-from repro.utils.compat import shard_map
+from jax import shard_map
 
 
 def local(pos, w, params):

@@ -9,7 +9,7 @@ checks:
 
   A1  no host round-trips: the jaxpr contains no callback / infeed /
       outfeed / device_put primitives (anywhere, including sub-jaxprs of
-      while/scan/pjit/shard_map) — a hot step must stay on device.
+      while/scan/jit/shard_map) — a hot step must stay on device.
   A2  dtype discipline: no float64/complex128 avals anywhere in the traced
       program (CPU silently eats f64; accelerators pay 2x for it).
   A3  donation: with ``donate_argnums_if_supported`` forced on (it is a
@@ -110,12 +110,12 @@ def _check_program(family: str, closed, failures: list) -> dict:
 
 
 def _donates_arg0(jitted, *args) -> bool:
-    """True if tracing ``jitted`` yields a top-level pjit that donates its
-    first argument (the position buffer)."""
+    """True if tracing ``jitted`` yields a top-level ``jit`` equation that
+    donates its first argument (the position buffer)."""
     import jax
     closed = jax.make_jaxpr(jitted)(*args)
     for eqn in closed.jaxpr.eqns:
-        if eqn.primitive.name == "pjit":
+        if eqn.primitive.name == "jit":
             donated = eqn.params.get("donated_invars")
             return bool(donated) and bool(donated[0])
     return False
