@@ -198,7 +198,7 @@ def jit_cache_entries() -> int:
         # dist-engine entries are (jitted_step, shardings) tuples
         fns.append(entry[0] if isinstance(entry, tuple) else entry)
     fns += [_merger.sun_election, _merger.system_growth,
-            _placer._place, gila._gila_forces, gila.gila_layout]
+            _placer._place, gila.gila_layout]
     total = 0
     for f in fns:
         size = getattr(f, "_cache_size", None)

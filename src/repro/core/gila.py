@@ -198,21 +198,10 @@ def gila_forces(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr,
     """Total force per vertex; ``params_arr = [C, L, min_dist]`` (traced).
 
     ``grid_dim``/``cell_cap`` are the static grid parameters for
-    ``mode="grid"`` (pick them with ``kernels.grid_force.choose_grid``)."""
-    return _gila_forces(g, pos, nbr_idx, nbr_mask, params_arr, mode=mode,
-                        grid_dim=grid_dim, cell_cap=cell_cap,
-                        backend=kernel_backend())
+    ``mode="grid"`` (pick them with ``kernels.grid_force.choose_grid``).
 
-
-@partial(jax.jit, static_argnames=("mode", "grid_dim", "cell_cap",
-                                   "backend"))
-def _gila_forces(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr, *,
-                 mode: str, grid_dim: int, cell_cap: int, backend: str):
-    """``gila_forces`` with the kernel backend in its trace-cache key: the
-    trace is reused inside every cached step that calls it, and a step
-    keyed on one ``REPRO_PALLAS`` backend must not inherit a trace of
-    another."""
-    del backend
+    Not jitted on its own: it is traced inline by the step that calls it,
+    so the kernel backend it reads belongs to that step's cache key."""
     C, L, min_dist = params_arr[0], params_arr[1], params_arr[2]
     if mode == "exact":
         rep = _repulsion_exact(pos, g.mass, g.vmask, C, L, min_dist)
@@ -238,20 +227,34 @@ def layout_iteration(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr,
     return jnp.where(g.vmask[:, None], pos, 0.0)
 
 
-@partial(jax.jit, static_argnames=("mode", "iters", "grid_dim", "cell_cap"))
+def check_backend(backend: str) -> None:
+    """Trace-time guard of a jitted function keyed on a static ``backend``:
+    the kernels about to be traced read ``kernel_backend()``, so the key
+    must name that same backend."""
+    if backend != kernel_backend():
+        raise ValueError(f"traced with backend={backend!r} while the "
+                         f"kernels would use {kernel_backend()!r}")
+
+
+@partial(jax.jit, static_argnames=("mode", "iters", "grid_dim", "cell_cap",
+                                   "backend"))
 def gila_layout(g: PaddedGraph, pos0, nbr_idx, nbr_mask, *, mode: str,
                 iters: int, temp0: float, temp_decay: float,
-                ideal_len: float, rep_const: float, min_dist: float = 1e-3,
-                grid_dim: int = 0, cell_cap: int = 0):
+                ideal_len: float, rep_const: float, backend: str,
+                min_dist: float = 1e-3, grid_dim: int = 0, cell_cap: int = 0):
     """Run ``iters`` force iterations with a cooling displacement clamp.
 
     In ``mode="grid"`` the spatial binning happens inside ``gila_forces``,
     i.e. vertices are rebinned on every iteration of the loop.
 
+    ``backend`` is ``repro.kernels.backend()`` as the caller sees it: the
+    trace bakes that kernel backend in, so it keys the trace cache.
+
     This is the exact-shape path: ``iters`` (and ``g.n``/``g.m``) are
     static, so every distinct level retraces. The multilevel driver uses
     the bucketed, compile-cached equivalent in core/bucketing.py unless
     ``LayoutConfig.bucketing=False``."""
+    check_backend(backend)
     params_arr = jnp.asarray([rep_const, ideal_len, min_dist], jnp.float32)
 
     def body(i, carry):

@@ -222,14 +222,15 @@ def _layout_one_level(g: PaddedGraph, pos0, sched: LevelSchedule,
                 iters=sched.iters, temp0=sched.temp0,
                 temp_decay=sched.temp_decay, alpha0=a0, alpha_decay=ad,
                 ideal_len=cfg.ideal_len, rep_const=cfg.rep_const,
+                backend=bucketing.kernel_backend(),
                 grid_dim=sched.grid_dim, cell_cap=sched.cell_cap)
         else:
             pos = gila.gila_layout(
                 g, pos0, nbr_idx, nbr_mask, mode=sched.mode,
                 iters=sched.iters, temp0=sched.temp0,
                 temp_decay=sched.temp_decay, ideal_len=cfg.ideal_len,
-                rep_const=cfg.rep_const, grid_dim=sched.grid_dim,
-                cell_cap=sched.cell_cap)
+                rep_const=cfg.rep_const, backend=bucketing.kernel_backend(),
+                grid_dim=sched.grid_dim, cell_cap=sched.cell_cap)
         pos.block_until_ready()             # keep device time in-phase
     return pos
 

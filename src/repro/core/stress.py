@@ -102,15 +102,18 @@ def stress_iteration(g: PaddedGraph, pos, nbr_idx, nbr_mask, ell, we, rho,
     return jnp.where(g.vmask[:, None], pos, 0.0)
 
 
-@partial(jax.jit, static_argnames=("mode", "iters", "grid_dim", "cell_cap"))
+@partial(jax.jit, static_argnames=("mode", "iters", "grid_dim", "cell_cap",
+                                   "backend"))
 def stress_layout(g: PaddedGraph, pos0, nbr_idx, nbr_mask, *, mode: str,
                   iters: int, temp0: float, temp_decay: float,
                   alpha0: float, alpha_decay: float, ideal_len: float,
-                  rep_const: float, min_dist: float = 1e-3,
+                  rep_const: float, backend: str, min_dist: float = 1e-3,
                   grid_dim: int = 0, cell_cap: int = 0):
     """Exact-shape maxent-stress loop — the ``gila.gila_layout`` analogue
     used when ``LayoutConfig.bucketing=False`` (every level retraces); the
-    multilevel driver uses the compile-cached builders below otherwise."""
+    multilevel driver uses the compile-cached builders below otherwise.
+    ``backend`` keys the trace on the kernel backend, as there."""
+    gila.check_backend(backend)
     params_arr = jnp.asarray([rep_const, ideal_len, min_dist], jnp.float32)
     ell, we, rho = stress_terms(g, params_arr[1])
 

@@ -11,6 +11,7 @@ Three contracts:
     forces, same merger decisions.
 """
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -108,8 +109,9 @@ def test_padding_invariance_of_init_forces_and_merger():
                  jnp.zeros((g1.n_pad, 1), bool))
         dummy2 = (jnp.zeros((g2.n_pad, 1), jnp.int32),
                   jnp.zeros((g2.n_pad, 1), bool))
-    f1 = gila.gila_forces(g1, pos1, *dummy, params, mode="exact")
-    f2 = gila.gila_forces(g2, pos2, *dummy2, params, mode="exact")
+    forces = jax.jit(gila.gila_forces, static_argnames=("mode",))
+    f1 = forces(g1, pos1, *dummy, params, mode="exact")
+    f2 = forces(g2, pos2, *dummy2, params, mode="exact")
     np.testing.assert_allclose(np.asarray(f1)[:n], np.asarray(f2)[:n],
                                atol=1e-5)
 

@@ -42,7 +42,8 @@ def test_layout_service_and_tile_phases_tiny(cs, capsys):
     (b) keeps bit-parity with the dedicated driver, (c) with the NumPy
     reference resolver."""
     exp = cs.phase_layout(24, LayoutConfig(**TINY), "interpret")
-    cs.phase_service((150, 180, 210), LayoutConfig(seed=0, **TINY))
+    cs.phase_service((150, 180, 210), LayoutConfig(seed=0, **TINY),
+                     expect_backend="interpret")
     cs.phase_tiles(exp)
     out = capsys.readouterr().out
     for tag in ("[a layout]", "[b service]", "[c tiles]"):

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 from repro.graphs import generators as G, build_graph
 from repro.core import gila
 from repro.core.schedule import make_schedule
+from repro.kernels import backend as kernel_backend
 
 
 def test_paper_k_schedule():
@@ -116,7 +117,34 @@ def test_layout_reduces_stress():
     pos1 = gila.gila_layout(g, pos0, jnp.zeros((g.n_pad, 1), jnp.int32),
                             jnp.zeros((g.n_pad, 1), bool), mode="exact",
                             iters=200, temp0=2.0, temp_decay=0.98,
-                            ideal_len=1.0, rep_const=1.0)
+                            ideal_len=1.0, rep_const=1.0,
+                            backend=kernel_backend())
     s0 = sampled_stress(np.asarray(pos0)[:n], e, n)
     s1 = sampled_stress(np.asarray(pos1)[:n], e, n)
     assert s1 < s0 * 0.5, (s0, s1)
+
+
+def test_gila_layout_traces_per_kernel_backend(monkeypatch):
+    """Switching ``REPRO_PALLAS`` mid-process gives ``gila_layout`` a trace
+    of its own: the interpret-mode trace holds the Pallas kernel, the
+    ref-mode one does not, and a key naming another backend than the
+    kernels would use is refused."""
+    import jax
+    e, n = G.grid(8, 8)
+    g = build_graph(e, n)
+    pos0 = gila.random_init(g, 4.0, 0)
+    dummy = (jnp.zeros((g.n_pad, 1), jnp.int32),
+             jnp.zeros((g.n_pad, 1), bool))
+    kw = dict(mode="exact", iters=5, temp0=1.0, temp_decay=0.9,
+              ideal_len=1.0, rep_const=1.0)
+    texts, outs = {}, {}
+    for b in ("ref", "interpret"):
+        monkeypatch.setenv("REPRO_PALLAS", b)
+        run = lambda p: gila.gila_layout(g, p, *dummy, backend=b, **kw)
+        texts[b] = str(jax.make_jaxpr(run)(pos0))
+        outs[b] = np.asarray(run(pos0))
+    assert "pallas_call" in texts["interpret"]
+    assert "pallas_call" not in texts["ref"]
+    np.testing.assert_allclose(outs["interpret"], outs["ref"], atol=1e-4)
+    with pytest.raises(ValueError, match="backend"):
+        gila.gila_layout(g, pos0, *dummy, backend="pallas", **kw)

@@ -155,6 +155,7 @@ def test_grid_mode_reduces_stress():
     from repro.graphs.graph import build_graph
     from repro.graphs.metrics import sampled_stress
     from repro.core import gila
+    from repro.kernels import backend
     e, n = GEN.grid(16, 16)
     g = build_graph(e, n)
     pos0 = gila.random_init(g, 6.0, 1)
@@ -163,7 +164,7 @@ def test_grid_mode_reduces_stress():
     dummy_m = jnp.zeros((g.n_pad, 1), bool)
     pos1 = gila.gila_layout(g, pos0, dummy_i, dummy_m, mode="grid",
                             iters=200, temp0=2.0, temp_decay=0.98,
-                            ideal_len=1.0, rep_const=1.0,
+                            ideal_len=1.0, rep_const=1.0, backend=backend(),
                             grid_dim=G, cell_cap=cap)
     s0 = sampled_stress(np.asarray(pos0)[:n], e, n)
     s1 = sampled_stress(np.asarray(pos1)[:n], e, n)

@@ -23,6 +23,7 @@ from repro.graphs.io import load_edgelist
 from repro.core import (LayoutConfig, multigila_layout,
                         multigila_layout_many, bucketing, gila, stress)
 from repro.core.engine import get_engine
+from repro.kernels import backend as kernel_backend
 from repro.core.pruning import prune_degree_one
 from repro.utils.transfer import io_boundary, no_implicit_transfers
 
@@ -66,7 +67,8 @@ def test_stress_layout_padding_invariant():
     g1 = build_graph(e, n, n_pad=1024, m_pad=8192)
     g2 = build_graph(e, n, n_pad=2048, m_pad=16384)
     kw = dict(mode="exact", iters=20, temp0=3.0, temp_decay=0.96,
-              alpha0=0.05, alpha_decay=0.9, ideal_len=1.0, rep_const=1.0)
+              alpha0=0.05, alpha_decay=0.9, ideal_len=1.0, rep_const=1.0,
+              backend=kernel_backend())
     with io_boundary():                 # test-side staging (dummies, scalars)
         p1 = stress.stress_layout(g1, gila.random_init(g1, 5.0, 3),
                                   jnp.zeros((g1.n_pad, 1), jnp.int32),

@@ -103,26 +103,51 @@ def test_exact_refine_step_compiles(S, monkeypatch):
     assert "tpu_custom_call" in text
 
 
-def test_sharded_grid_step_compiles_on_four_chips(topo, S, monkeypatch):
-    """The grid-mode superstep of ``driver="multigila_dist"`` on a (4, 1)
-    mesh of described chips: the Pallas near and far kernels inside the
-    vma-checked ``shard_map``."""
+def _compile_sharded_step(topo, mode, n_pad, m_pad, cap):
+    """The superstep of ``driver="multigila_dist"`` for one level, compiled
+    on a (4, 1) mesh of described chips."""
     import numpy as np
     from jax.sharding import Mesh
     from repro.core.distributed import layout_train_step, layout_step_specs
     from repro.kernels.grid_force.ops import choose_grid
-    monkeypatch.setenv("REPRO_PALLAS", "pallas")
     mesh = Mesh(np.array(topo.devices[:4]).reshape(4, 1), ("data", "model"),
                 axis_types=(jax.sharding.AxisType.Auto,) * 2)
-    n_pad, m_pad = 8192, 32768
-    G, cc = choose_grid(n_pad)
-    step, sh = layout_train_step(mesh, n_pad, m_pad, 1, mode="grid",
+    G, cc = choose_grid(n_pad) if mode == "grid" else (0, 0)
+    step, sh = layout_train_step(mesh, n_pad, m_pad, cap, mode=mode,
                                  grid_dim=G, cell_cap=cc)
-    specs = layout_step_specs(n_pad, m_pad, 1, mode="grid")
+    specs = layout_step_specs(n_pad, m_pad, cap, mode=mode)
     kind = dict(pos="pos", w="w", nbr_idx="nbr_idx", src="edge",
                 dst_local="edge", emask="edge", ewt="edge", params="scalar",
                 temp="scalar")
     args = [jax.ShapeDtypeStruct(specs[k].shape, specs[k].dtype,
                                  sharding=sh[v]) for k, v in kind.items()]
-    text = jax.jit(step).lower(*args).compile().as_text()
+    return jax.jit(step).lower(*args).compile()
+
+
+def test_sharded_grid_step_compiles_on_four_chips(topo, S, monkeypatch):
+    """The grid-mode superstep of ``driver="multigila_dist"`` on a (4, 1)
+    mesh of described chips: the Pallas near and far kernels inside the
+    vma-checked ``shard_map``."""
+    monkeypatch.setenv("REPRO_PALLAS", "pallas")
+    text = _compile_sharded_step(topo, "grid", 8192, 32768, 1).as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("mode,n_pad,m_pad,cap", [
+    ("grid", 262144, 1 << 20, 1), ("neighbor", 32768, 1 << 18, 256)],
+    ids=["grid", "neighbor"])
+def test_sharded_steps_compile_at_paper_scale(topo, S, monkeypatch, mode,
+                                              n_pad, m_pad, cap):
+    """The sharded supersteps of grid_400x400 on four chips: its finest
+    level (160,000 vertices padded to 2^18, 638,400 half-edges partitioned
+    into four pow2 blocks of 2^18, G = 128, cap = 48) and its largest
+    neighbor level (up to 2^15 vertices, K up to 256). Each must fit one
+    chip's 16 GiB; the grid step runs the Pallas kernels."""
+    monkeypatch.setenv("REPRO_PALLAS", "pallas")
+    compiled = _compile_sharded_step(topo, mode, n_pad, m_pad, cap)
+    mem = compiled.memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert used < 16 << 30, used
+    if mode == "grid":
+        assert "tpu_custom_call" in compiled.as_text()
