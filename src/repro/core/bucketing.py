@@ -227,6 +227,12 @@ obs_metrics.REGISTRY.gauge(
 REFINE_DISPATCHES = obs_metrics.REGISTRY.counter(
     "gila_refine_dispatches_total",
     "Cached refine-step dispatches, labeled by engine and dispatch path")
+# the iteration count handed to each dispatched step, which its fori_loop
+# runs exactly (the batched step: its lanes' largest budget); host ints,
+# so counting reads nothing back from the device
+REFINE_ITERATIONS = obs_metrics.REGISTRY.counter(
+    "gila_refine_iterations_total",
+    "Iterations run by cached refine steps, labeled by engine and mode")
 
 
 # -- the bucketed refinement step ----------------------------------------------
@@ -259,7 +265,8 @@ def cached_refine(g: PaddedGraph, pos0, sched, nbr_idx, nbr_mask, *,
     fn, fresh = STEP_CACHE.get(
         key, lambda: eng.build_refine(sched.mode, sched.grid_dim,
                                       sched.cell_cap))
-    with io_boundary():                     # intentional host→device staging
+    with obs_trace.span("refine.stage", cat="host"), \
+            io_boundary():                  # intentional host→device staging
         params = jnp.asarray([rep_const, ideal_len, min_dist], jnp.float32)
         args = (jnp.asarray(pos0), g.src, g.dst, g.vmask, g.emask, g.mass,
                 g.ewt, nbr_idx, nbr_mask,
@@ -278,7 +285,8 @@ def refine_level(g: PaddedGraph, pos0, sched, *, ideal_len: float,
     """
     eng = engines.get_engine(sched.engine)
     if sched.mode == "neighbor":
-        with PHASES.phase("refine"):        # host-side k-hop list build
+        with PHASES.phase("refine"), obs_trace.span(     # host k-hop lists
+                "refine.khop", cat="host", n=g.n, k=sched.k, cap=sched.cap):
             nbr_idx, nbr_mask = eng.init_state(g, sched, seed)
     else:
         nbr_idx, nbr_mask = eng.init_state(g, sched, seed)
@@ -292,11 +300,13 @@ def refine_level(g: PaddedGraph, pos0, sched, *, ideal_len: float,
     # NO new host↔device sync is introduced by tracing (gilalint-checked)
     t0 = time.perf_counter()
     with obs_trace.span("refine.dispatch", cat="device", key=key,
-                        fresh=fresh, mode=sched.mode, engine=sched.engine):
+                        fresh=fresh, mode=sched.mode, engine=sched.engine,
+                        iters=sched.iters):
         pos = fn(*args)
         pos.block_until_ready()
     PHASES.add("compile" if fresh else "refine", time.perf_counter() - t0)
     REFINE_DISPATCHES.inc(engine=sched.engine, path="single")
+    REFINE_ITERATIONS.inc(sched.iters, engine=sched.engine, mode=sched.mode)
     return pos
 
 
@@ -379,32 +389,31 @@ def group_key(req: RefineRequest) -> tuple:
             s.grid_dim, s.cell_cap)
 
 
-# padding occupancy — the direct measurement of fragmentation loss: the
-# fraction of each dispatched [lanes, n_pad]/[lanes, m_pad] batch volume
-# holding TRUE vertices/edge-slots rather than pow2 padding. Labeled by
-# the shape bucket (and by lane bucket for the lane axis).
-OCC_VERTICES = obs_metrics.REGISTRY.gauge(
-    "gila_wave_padding_occupancy_vertices",
-    "True vertices / (lanes * n_pad) of the last dispatch per bucket",
-    "ratio")
-OCC_EDGES = obs_metrics.REGISTRY.gauge(
-    "gila_wave_padding_occupancy_edges",
-    "True directed edge slots / (lanes * m_pad) of the last dispatch",
-    "ratio")
-OCC_LANES = obs_metrics.REGISTRY.gauge(
-    "gila_wave_lane_occupancy",
-    "Live lanes / pow2 lane bucket of the last dispatch per bucket",
-    "ratio")
+# padding occupancy — the direct measurement of fragmentation loss: per
+# shape bucket and axis (vertices, edges, lanes), the slots each dispatched
+# [lanes, n_pad] / [lanes, m_pad] batch holds TRUE vertices / edge slots /
+# live lanes, and the slots it dispatched in all. Counters sum over any
+# window: two scrapes give the occupancy between them as the ratio of the
+# two deltas.
+OCC_TRUE = obs_metrics.REGISTRY.counter(
+    "gila_wave_true_slots_total",
+    "True vertices, directed edge slots or live lanes of batched dispatches,"
+    " labeled by shape bucket and axis")
+OCC_PADDED = obs_metrics.REGISTRY.counter(
+    "gila_wave_padded_slots_total",
+    "Dispatched (padded) slots of batched dispatches, labeled by shape "
+    "bucket and axis")
 
 
 def _record_occupancy(reqs: list["RefineRequest"], lanes: int) -> None:
     n_pad, m_pad = reqs[0].g.n_pad, reqs[0].g.m_pad
     bucket = f"n{n_pad}_e{m_pad}"
-    OCC_VERTICES.set(sum(r.g.n for r in reqs) / (lanes * n_pad),
-                     bucket=bucket)
-    OCC_EDGES.set(sum(2 * r.g.m for r in reqs) / (lanes * m_pad),
-                  bucket=bucket)
-    OCC_LANES.set(len(reqs) / lanes, bucket=bucket)
+    for axis, true, padded in (
+            ("vertices", sum(r.g.n for r in reqs), lanes * n_pad),
+            ("edges", sum(2 * r.g.m for r in reqs), lanes * m_pad),
+            ("lanes", len(reqs), lanes)):
+        OCC_TRUE.inc(true, bucket=bucket, axis=axis)
+        OCC_PADDED.inc(padded, bucket=bucket, axis=axis)
 
 
 def _build_refine_many(mode: str, grid_dim: int, cell_cap: int, inc_k: int,
@@ -495,7 +504,8 @@ def refine_level_many(reqs: list[RefineRequest], *, ideal_len: float,
     # same code path + seed as the single-graph driver so the lists — and
     # hence the forces — match)
     if mode == "neighbor":
-        with PHASES.phase("refine"):
+        with PHASES.phase("refine"), obs_trace.span(
+                "refine.khop", cat="host", lanes=len(reqs)):
             nbrs = [eng.init_state(r.g, r.sched, r.seed) for r in reqs]
     else:
         z = eng.init_state(reqs[0].g, reqs[0].sched, reqs[0].seed)
@@ -504,15 +514,19 @@ def refine_level_many(reqs: list[RefineRequest], *, ideal_len: float,
     key, fn, fresh, args = cached_refine_many(
         reqs, nbrs, ideal_len=ideal_len, rep_const=rep_const,
         min_dist=min_dist, lanes_min=lanes_min)
-    # span brackets the existing dispatch + sync only (no added syncs)
+    # span brackets the existing dispatch + sync only (no added syncs);
+    # every lane rides the loop's max_iters trips (idle past its own budget)
+    iters = max(r.sched.iters for r in reqs)
+    engine = reqs[0].sched.engine
     t0 = time.perf_counter()
     with obs_trace.span("refine_many.dispatch", cat="device", key=key,
-                        fresh=fresh, lanes=len(reqs),
-                        engine=reqs[0].sched.engine):
+                        fresh=fresh, lanes=len(reqs), engine=engine,
+                        mode=mode, iters=iters):
         out = fn(*args)
         out.block_until_ready()
     PHASES.add("compile" if fresh else "refine", time.perf_counter() - t0)
-    REFINE_DISPATCHES.inc(engine=reqs[0].sched.engine, path="many")
+    REFINE_DISPATCHES.inc(engine=engine, path="many")
+    REFINE_ITERATIONS.inc(iters, engine=engine, mode=mode)
     b = len(reqs)
     with io_boundary():                     # egress: unpack the live lanes
         return [out[i] for i in range(b)]

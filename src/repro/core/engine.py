@@ -216,11 +216,17 @@ class GilaEngine(RefinementEngine):
 
             def body(i, carry):
                 pos, temp = carry
-                f = repulsion(pos) + attraction(pos)
-                norm = jnp.sqrt(jnp.sum(f * f, axis=2) + 1e-12)
-                step = jnp.minimum(norm, temp[:, None])
-                new = pos + f / norm[..., None] * step[..., None]
-                new = jnp.where(vmask[..., None], new, 0.0)
+                # the single-graph step's scopes (gila.layout_iteration)
+                with jax.named_scope("gila.repulsion"):
+                    rep = repulsion(pos)
+                with jax.named_scope("gila.attraction"):
+                    att = attraction(pos)
+                f = rep + att
+                with jax.named_scope("gila.move"):
+                    norm = jnp.sqrt(jnp.sum(f * f, axis=2) + 1e-12)
+                    step = jnp.minimum(norm, temp[:, None])
+                    new = pos + f / norm[..., None] * step[..., None]
+                    new = jnp.where(vmask[..., None], new, 0.0)
                 live = i < iters
                 return (jnp.where(live[:, None, None], new, pos),
                         jnp.where(live, temp * temp_decay, temp))

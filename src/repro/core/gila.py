@@ -201,17 +201,22 @@ def gila_forces(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr,
     ``mode="grid"`` (pick them with ``kernels.grid_force.choose_grid``).
 
     Not jitted on its own: it is traced inline by the step that calls it,
-    so the kernel backend it reads belongs to that step's cache key."""
+    so the kernel backend it reads belongs to that step's cache key.
+
+    The named scopes (``gila.repulsion``, ``gila.attraction``) only label
+    the compiled operations, so a device profile names each stage."""
     C, L, min_dist = params_arr[0], params_arr[1], params_arr[2]
-    if mode == "exact":
-        rep = _repulsion_exact(pos, g.mass, g.vmask, C, L, min_dist)
-    elif mode == "grid":
-        rep = _repulsion_grid(pos, g.mass, g.vmask, C, L, min_dist,
-                              grid_dim, cell_cap)
-    else:
-        rep = _repulsion_neighbors(pos, g.mass, nbr_idx, nbr_mask, g.vmask,
-                                   C, L, min_dist)
-    att = _attraction(g, pos, L, min_dist)
+    with jax.named_scope("gila.repulsion"):
+        if mode == "exact":
+            rep = _repulsion_exact(pos, g.mass, g.vmask, C, L, min_dist)
+        elif mode == "grid":
+            rep = _repulsion_grid(pos, g.mass, g.vmask, C, L, min_dist,
+                                  grid_dim, cell_cap)
+        else:
+            rep = _repulsion_neighbors(pos, g.mass, nbr_idx, nbr_mask,
+                                       g.vmask, C, L, min_dist)
+    with jax.named_scope("gila.attraction"):
+        att = _attraction(g, pos, L, min_dist)
     return rep + att
 
 
@@ -221,10 +226,11 @@ def layout_iteration(g: PaddedGraph, pos, nbr_idx, nbr_mask, params_arr,
     ``gila_layout`` and the bucketed cached step in core/bucketing.py)."""
     f = gila_forces(g, pos, nbr_idx, nbr_mask, params_arr, mode=mode,
                     grid_dim=grid_dim, cell_cap=cell_cap)
-    norm = jnp.sqrt(jnp.sum(f * f, axis=1) + 1e-12)
-    step = jnp.minimum(norm, temp)
-    pos = pos + f / norm[:, None] * step[:, None]
-    return jnp.where(g.vmask[:, None], pos, 0.0)
+    with jax.named_scope("gila.move"):
+        norm = jnp.sqrt(jnp.sum(f * f, axis=1) + 1e-12)
+        step = jnp.minimum(norm, temp)
+        pos = pos + f / norm[:, None] * step[:, None]
+        return jnp.where(g.vmask[:, None], pos, 0.0)
 
 
 def check_backend(backend: str) -> None:

@@ -298,7 +298,8 @@ def layout_component(edges: np.ndarray, n: int, cfg: LayoutConfig,
     if n == 1:
         return ret(np.zeros((1, 2), np.float32), stats)
     if cfg.prune and cfg.driver != "flat":
-        pr = prune_degree_one(edges, n, weights=weights)
+        with obs_trace.span("layout.prune", cat="host", n=n):
+            pr = prune_degree_one(edges, n, weights=weights)
     else:
         pr = None
 
@@ -311,8 +312,9 @@ def layout_component(edges: np.ndarray, n: int, cfg: LayoutConfig,
         pos = reinsert(pr, np.zeros((max(work_n, 1), 2), np.float32), work_edges) \
             if pr is not None else np.zeros((n, 2), np.float32)
         return ret(pos, stats)
-    g0 = build_graph(work_edges, work_n, mass=mass, ewt=work_ewt,
-                     bucket=cfg.bucketing)
+    with obs_trace.span("layout.build", cat="host", n=work_n):
+        g0 = build_graph(work_edges, work_n, mass=mass, ewt=work_ewt,
+                         bucket=cfg.bucketing)
 
     if cfg.driver == "flat":
         sched = make_schedule(0, 1, g0.n, g0.m,
@@ -366,10 +368,12 @@ def layout_component(edges: np.ndarray, n: int, cfg: LayoutConfig,
         with obs_trace.span("refine.level", level=i, n=gi.n):
             pos = _layout_one_level(gi, pos, sched, cfg, cfg.seed + i)
 
-    pos = np.asarray(pos, np.float32)[: g0.n]
-    if pr is not None:
-        pos = reinsert(pr, pos, work_edges)
-    pos = pos[:n] if pr is None else pos
+    with obs_trace.span("layout.finish", cat="host", n=n):
+        # read-back of the finest level, then the pruned leaves
+        pos = np.asarray(pos, np.float32)[: g0.n]
+        if pr is not None:
+            pos = reinsert(pr, pos, work_edges)
+        pos = pos[:n] if pr is None else pos
     return ret(pos, stats, graphs=graphs, infos=infos, pr=pr)
 
 
@@ -760,6 +764,8 @@ class WaveScheduler:
             tg0 = self.clock.now()
             outs = self._dispatch([r for _, r in members])
             tg1 = self.clock.now()
+            gid = self.tracer.complete("refine.group", tg0, tg1, cat="wave",
+                                       bucket=key, lanes=len(members))
             for (t, r), pos in zip(members, outs):
                 del self._staged[t]
                 t.feed(pos)
@@ -767,9 +773,7 @@ class WaveScheduler:
                 # as the group span, annotated with level/lane so phase
                 # sums and host/device overlap are computable per lane
                 self.tracer.complete("refine", tg0, tg1, cat="wave",
-                                     level=r.level, lane=r.lane)
-            self.tracer.complete("refine.group", tg0, tg1, cat="wave",
-                                 bucket=key, lanes=len(members))
+                                     parent=gid, level=r.level, lane=r.lane)
             GROUP_LANES_HIST.observe(len(members))
             ginfo.append((key, len(members)))
         if pend:
@@ -845,32 +849,35 @@ def multigila_layout(edges: np.ndarray, n: int,
     edges = np.asarray(edges, dtype=np.int64).reshape(-1, 2)
     if weights is not None:
         weights = np.asarray(weights, np.float32).reshape(-1)
-    labels = connected_components(edges, n)
-    comps = np.unique(labels)
-    stats = LayoutStats()
-    if len(comps) == 1:
-        return layout_component(edges, n, cfg, export=export,
-                                weights=weights)
+    # the root span: every span of this layout carries its layout_id
+    with obs_trace.root("layout", cat="host", n=n, m=len(edges)):
+        with obs_trace.span("layout.components", cat="host", n=n):
+            labels = connected_components(edges, n)
+            comps = np.unique(labels)
+        stats = LayoutStats()
+        if len(comps) == 1:
+            return layout_component(edges, n, cfg, export=export,
+                                    weights=weights)
 
-    layouts, index_maps, exports = [], [], []
-    for c in comps:
-        vs = np.nonzero(labels == c)[0]
-        remap = np.full(n, -1, np.int64)
-        remap[vs] = np.arange(vs.size)
-        emask = labels[edges[:, 0]] == c
-        ce = np.stack([remap[edges[emask, 0]], remap[edges[emask, 1]]], 1)
-        cw = weights[emask] if weights is not None else None
-        out = layout_component(ce, vs.size, cfg, export=export, weights=cw)
-        p, s = out[0], out[1]
-        if export:
-            exports.append(out[2])
-        stats.levels = max(stats.levels, s.levels)
-        layouts.append(np.asarray(p))
-        index_maps.append(vs)
-    packed = _pack_components(layouts)
-    pos = np.zeros((n, 2), np.float32)
-    for vs, P in zip(index_maps, packed):
-        pos[vs] = P
-    if not export:
-        return pos, stats
-    return pos, stats, _merge_exports(exports, index_maps, edges, n, pos)
+        layouts, index_maps, exports = [], [], []
+        for c in comps:
+            vs = np.nonzero(labels == c)[0]
+            remap = np.full(n, -1, np.int64)
+            remap[vs] = np.arange(vs.size)
+            emask = labels[edges[:, 0]] == c
+            ce = np.stack([remap[edges[emask, 0]], remap[edges[emask, 1]]], 1)
+            cw = weights[emask] if weights is not None else None
+            out = layout_component(ce, vs.size, cfg, export=export, weights=cw)
+            p, s = out[0], out[1]
+            if export:
+                exports.append(out[2])
+            stats.levels = max(stats.levels, s.levels)
+            layouts.append(np.asarray(p))
+            index_maps.append(vs)
+        packed = _pack_components(layouts)
+        pos = np.zeros((n, 2), np.float32)
+        for vs, P in zip(index_maps, packed):
+            pos[vs] = P
+        if not export:
+            return pos, stats
+        return pos, stats, _merge_exports(exports, index_maps, edges, n, pos)

@@ -381,11 +381,12 @@ def _merger_sort_args(g: PaddedGraph):
     (every consumer is a max), so stable-vs-quicksort changes can't
     perturb results.
     """
-    with io_boundary():                 # egress: graph topology (host sort)
-        dst = np.asarray(g.dst)
-    order = np.argsort(dst).astype(np.int32)   # unstable is fine: see above
-    with io_boundary():                 # staging: permutation → device
-        return jnp.asarray(order)
+    with obs_trace.span("coarsen.sort", cat="host", m_pad=g.m_pad):
+        with io_boundary():             # egress: graph topology (host sort)
+            dst = np.asarray(g.dst)
+        order = np.argsort(dst).astype(np.int32)   # unstable is fine
+        with io_boundary():             # staging: permutation → device
+            return jnp.asarray(order)
 
 
 def cached_merger(g: PaddedGraph, st: MergerState, key: jnp.ndarray, *,

@@ -290,7 +290,10 @@ def grid_repulsion(pos, mass, vmask, C, L, min_dist, *,
     """Grid-approximated FR repulsion: pos f32[n, 2] → forces f32[n, 2].
 
     Static ``grid_dim``/``cell_cap`` (pick with ``choose_grid``); all array
-    work is traced, so the op rebins on every call.
+    work is traced, so the op rebins on every call. Each stage runs under
+    a named scope (``grid.bin``, ``grid.aggregates``, ``grid.near``,
+    ``grid.far``, ``grid.corrections``) that labels its device operations
+    in a profile and changes nothing else.
     """
     assert grid_dim >= 2 and cell_cap >= 1, (grid_dim, cell_cap)
     mode = kernel_backend()
@@ -300,36 +303,41 @@ def grid_repulsion(pos, mass, vmask, C, L, min_dist, *,
     pos = pos.astype(jnp.float32)
     w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)
 
-    cid, bucket, inb = bin_vertices(pos, vmask, G, cap)
-    M_full, S_full, mu_full = _cell_aggregates(pos, w, cid, nc)
-    w_out = jnp.where(inb, 0.0, w)
-    M_out, S_out, _ = _cell_aggregates(pos, w_out, cid, nc)
-    # per-cell second moments → RMS radii (for near-range softening),
-    # accumulated about the cell centers (see cell_centers on conditioning)
-    centers = cell_centers(pos, vmask, G)
-    q = jnp.sum((pos - centers[cid]) ** 2, axis=1)
-    Q_full = jax.ops.segment_sum(w * q, cid, num_segments=nc + 1)
-    Q_out = jax.ops.segment_sum(w_out * q, cid, num_segments=nc + 1)
+    with jax.named_scope("grid.bin"):
+        cid, bucket, inb = bin_vertices(pos, vmask, G, cap)
+    with jax.named_scope("grid.aggregates"):
+        M_full, S_full, mu_full = _cell_aggregates(pos, w, cid, nc)
+        w_out = jnp.where(inb, 0.0, w)
+        M_out, S_out, _ = _cell_aggregates(pos, w_out, cid, nc)
+        # per-cell second moments → RMS radii (for near-range softening),
+        # accumulated about the cell centers (see cell_centers)
+        centers = cell_centers(pos, vmask, G)
+        q = jnp.sum((pos - centers[cid]) ** 2, axis=1)
+        Q_full = jax.ops.segment_sum(w * q, cid, num_segments=nc + 1)
+        Q_out = jax.ops.segment_sum(w_out * q, cid, num_segments=nc + 1)
 
     # -- near field: exact within the 3×3 neighborhood ------------------------
     # gathered straight into the kernel's lane-major planes (cells on lanes)
-    table = jnp.asarray(_neighbor_table(G))                 # [nc+1, 9]
-    xyw_p = jnp.pad(jnp.concatenate([pos.T, w[None]], axis=0),
-                    ((0, 0), (0, 1)))                       # [3, n+1]
-    rows_idx = bucket[:nc]                                  # [nc, cap]
-    nbr_bucket = bucket[table[:nc]].reshape(nc, 9 * cap)
-    near = near_field(xyw_p[:2, rows_idx.T], xyw_p[:, nbr_bucket.T],
-                      C, L, min_dist, backend=mode)         # [2, cap, nc]
-    f_near = jnp.zeros((n + 1, 2), jnp.float32).at[
-        rows_idx.reshape(-1)].set(
-        jnp.transpose(near, (2, 1, 0)).reshape(-1, 2))[:n]
+    with jax.named_scope("grid.near"):
+        table = jnp.asarray(_neighbor_table(G))             # [nc+1, 9]
+        xyw_p = jnp.pad(jnp.concatenate([pos.T, w[None]], axis=0),
+                        ((0, 0), (0, 1)))                   # [3, n+1]
+        rows_idx = bucket[:nc]                              # [nc, cap]
+        nbr_bucket = bucket[table[:nc]].reshape(nc, 9 * cap)
+        near = near_field(xyw_p[:2, rows_idx.T], xyw_p[:, nbr_bucket.T],
+                          C, L, min_dist, backend=mode)     # [2, cap, nc]
+        f_near = jnp.zeros((n + 1, 2), jnp.float32).at[
+            rows_idx.reshape(-1)].set(
+            jnp.transpose(near, (2, 1, 0)).reshape(-1, 2))[:n]
 
     # -- far field: all-cell aggregates, near cells swapped for overflow ------
-    cell_xyw = jnp.concatenate([mu_full[:nc], M_full[:nc, None]], axis=1)
-    f_far = _far_all_cells(pos, cell_xyw, C, L, min_dist, mode)
-    f_far += far_corrections(pos, w_out, cid, inb,
-                             M_full, S_full, Q_full, M_out, S_out, Q_out,
-                             C, L, min_dist, grid_dim=G, centers=centers)
+    with jax.named_scope("grid.far"):
+        cell_xyw = jnp.concatenate([mu_full[:nc], M_full[:nc, None]], axis=1)
+        f_far = _far_all_cells(pos, cell_xyw, C, L, min_dist, mode)
+    with jax.named_scope("grid.corrections"):
+        f_far += far_corrections(pos, w_out, cid, inb,
+                                 M_full, S_full, Q_full, M_out, S_out, Q_out,
+                                 C, L, min_dist, grid_dim=G, centers=centers)
 
     return jnp.where(vmask[:, None], f_near + f_far, 0.0)
 

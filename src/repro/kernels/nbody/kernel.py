@@ -96,6 +96,7 @@ def _nbody_call(rows, src, C, L, md, *, block_rows: int, block_cols: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="nbody_pallas",        # a stable kernel name for profiles
     )(params, sx, sy, sw, rows.reshape(B, 2, npad // LANES, LANES))
     return out.reshape(B, 2, npad)[:, :, :n]
 

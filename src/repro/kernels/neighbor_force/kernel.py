@@ -81,6 +81,7 @@ def _neighbor_call(rows, nbrs, C, L, md, *, block_cols: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
+        name="neighbor_pallas",     # a stable kernel name for profiles
     )(params, rows, nbrs)
     return out[..., :N]
 

@@ -166,7 +166,7 @@ def smoke() -> None:
                                     timeout=60) as resp:
             prom = resp.read().decode()
         assert "gila_compile_cache_hits_total" in prom, prom[:400]
-        assert "gila_wave_padding_occupancy_vertices" in prom, prom[:400]
+        assert "gila_wave_padded_slots_total" in prom, prom[:400]
         eng = {k: v for k, v in stats["engine"].items() if k != "metrics"}
         print(f"[service] smoke OK: {eng}", flush=True)
     finally:

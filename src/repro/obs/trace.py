@@ -29,6 +29,21 @@ Design constraints, in order:
 Spans must close on the thread that opened them (the usual
 ``with span(...)`` shape guarantees it); cross-thread intervals are
 emitted with explicit times via ``complete``.
+
+**Identity.** Every span carries ``span_id`` and, when it has one,
+``parent_id`` (the span open around it on the same thread, or the
+explicit ``parent=`` of ``complete``) among its args. A span opened with
+``root`` starts a new ``layout_id`` — its own span id — which every span
+recorded on that thread while it is open carries. Ids come from a
+per-``Tracer`` counter that ``reset`` clears, so a replay under
+``VirtualClock`` exports the same ids.
+
+**The profiler mirror.** While the tracer is enabled, each ``span``
+also opens a ``jax.profiler.TraceAnnotation`` of the same name (the
+``annotation`` hook, imported lazily by ``enable`` so this package never
+imports jax at module level). A profiler capture then holds the
+program's spans on the profiler's own host clock, over the device
+operations they launched.
 """
 from __future__ import annotations
 
@@ -58,22 +73,42 @@ def _json_safe(v):
 class _Span:
     """Context object for one open span; created only when tracing is ON."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_ann", "_root",
+                 "id", "layout_id")
 
-    def __init__(self, tr: "Tracer", name: str, cat: str, args: dict):
+    def __init__(self, tr: "Tracer", name: str, cat: str, args: dict,
+                 root: bool = False):
         self._tr = tr
         self._name = name
         self._cat = cat
         self._args = args
+        self._root = root
 
     def __enter__(self):
-        self._t0 = self._tr.clock.now()
+        tr = self._tr
+        stack = tr._stack()
+        parent = stack[-1] if stack else None
+        ids = tr._ids(parent.id if parent is not None else None,
+                      parent.layout_id if parent is not None else None,
+                      root=self._root)
+        self.id, self.layout_id = ids["span_id"], ids.get("layout_id")
+        self._args = {**self._args, **ids}
+        stack.append(self)
+        ann = tr.annotation
+        self._ann = ann(self._name) if ann is not None else None
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = tr.clock.now()
         return self
 
     def __exit__(self, *exc):
         tr = self._tr
-        tr._append("X", self._name, self._cat, self._t0,
-                   tr.clock.now() - self._t0, self._args)
+        t1 = tr.clock.now()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        tr._stack().pop()
+        tr._append("X", self._name, self._cat, self._t0, t1 - self._t0,
+                   self._args)
         return False
 
 
@@ -88,15 +123,26 @@ class Tracer:
 
     def __init__(self, clock: Clock | None = None, *, enabled: bool = False):
         self.clock = clock or SystemClock()
-        self.enabled = bool(enabled)
+        self.enabled = False
+        #: ``name -> context manager`` opened around every span while
+        #: enabled (the profiler mirror); None mirrors nothing
+        self.annotation = None
         self._lock = threading.Lock()
+        self._local = threading.local()     # per-thread stack of open spans
         # (ph, name, cat, t_seconds, dur_seconds, thread_name, args)
         self._events: list[tuple] = []
+        self._last_id = 0
+        if enabled:
+            self.enable()
 
     # -- control ---------------------------------------------------------------
     def enable(self, clock: Clock | None = None) -> None:
         if clock is not None:
             self.clock = clock
+        if self.annotation is None:
+            # imported here: obs/ imports no jax at module level
+            from jax.profiler import TraceAnnotation
+            self.annotation = TraceAnnotation
         self.enabled = True
 
     def disable(self) -> None:
@@ -105,6 +151,27 @@ class Tracer:
     def reset(self) -> None:
         with self._lock:
             self._events.clear()
+            self._last_id = 0
+
+    def _ids(self, parent: int | None, layout: int | None, *,
+             root: bool = False) -> dict:
+        """A new span's identity args: its ``span_id``, ``parent_id`` and
+        ``layout_id`` (``root``: its own id), the last two where set."""
+        with self._lock:
+            self._last_id += 1
+            sid = self._last_id
+        ids = {"span_id": sid}
+        if parent is not None:
+            ids["parent_id"] = parent
+        if root or layout is not None:
+            ids["layout_id"] = sid if root else layout
+        return ids
+
+    def _stack(self) -> list:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     def __len__(self) -> int:
         with self._lock:
@@ -118,14 +185,32 @@ class Tracer:
             return _NULL
         return _Span(self, name, cat, args)
 
+    def root(self, name: str, cat: str = "", **args):
+        """``with tracer.root("layout", n=n): ...`` — a span that starts a
+        new ``layout_id`` (its own span id), carried by every span
+        recorded on this thread while it is open."""
+        if not self.enabled:
+            return _NULL
+        return _Span(self, name, cat, args, root=True)
+
     def complete(self, name: str, t0: float, t1: float, cat: str = "",
-                 **args) -> None:
+                 parent: int | None = None, **args) -> int | None:
         """A finished interval with explicit clock-frame times — for
         spans whose bounds were observed elsewhere (request lifetimes,
-        per-lane shares of a fused group dispatch)."""
+        per-lane shares of a fused group dispatch). Its parent is
+        ``parent`` (a span id this tracer returned) or else the span open
+        around the call on this thread, whose ``layout_id`` it carries.
+        Returns the new span's id."""
         if not self.enabled:
-            return
-        self._append("X", name, cat, float(t0), float(t1) - float(t0), args)
+            return None
+        stack = self._stack()
+        top = stack[-1] if stack else None
+        if parent is None and top is not None:
+            parent = top.id
+        ids = self._ids(parent, top.layout_id if top is not None else None)
+        self._append("X", name, cat, float(t0), float(t1) - float(t0),
+                     {**args, **ids})
+        return ids["span_id"]
 
     def instant(self, name: str, ts: float | None = None, cat: str = "",
                 **args) -> None:
@@ -202,8 +287,13 @@ def span(name: str, cat: str = "", **args):
     return _NULL if not TRACER.enabled else _Span(TRACER, name, cat, args)
 
 
-def complete(name: str, t0: float, t1: float, cat: str = "", **args) -> None:
-    TRACER.complete(name, t0, t1, cat, **args)
+def root(name: str, cat: str = "", **args):
+    return TRACER.root(name, cat, **args)
+
+
+def complete(name: str, t0: float, t1: float, cat: str = "",
+             parent: int | None = None, **args) -> int | None:
+    return TRACER.complete(name, t0, t1, cat, parent, **args)
 
 
 def instant(name: str, ts: float | None = None, cat: str = "", **args) -> None:

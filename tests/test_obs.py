@@ -35,6 +35,9 @@ def test_disabled_tracer_emits_nothing_and_allocates_no_contexts():
     # the module-level hooks share the same fast path object
     assert not obs_trace.TRACER.enabled
     assert obs_trace.span("a") is tr.span("b")
+    assert tr.root("layout") is obs_trace._NULL
+    assert obs_trace.root("layout", n=1) is obs_trace._NULL
+    assert tr.complete("r", 0.0, 1.0) is None
 
 
 def test_span_nesting_and_export_shape():
@@ -57,8 +60,10 @@ def test_span_nesting_and_export_shape():
     assert by_name["inner"]["dur"] == 0.5e6
     assert by_name["outer"]["ts"] == 0.0
     assert by_name["outer"]["dur"] == 1.5e6
-    assert by_name["outer"]["args"] == {"level": 1}
-    assert by_name["inner"]["args"] == {"key": [64, 512]}  # json-safe tuples
+    # the span's own args, then its id and its parent's
+    assert by_name["outer"]["args"] == {"level": 1, "span_id": 1}
+    assert by_name["inner"]["args"] == {"key": [64, 512],  # json-safe tuples
+                                        "span_id": 2, "parent_id": 1}
     assert by_name["mark"]["ph"] == "i" and by_name["mark"]["ts"] == 0.25e6
     assert by_name["depth"]["ph"] == "C"
     json.loads(tr.json_bytes())             # valid JSON document
@@ -81,6 +86,220 @@ def test_tracer_thread_tracks_use_names_not_os_ids():
     by = {e["name"]: e for e in evs if e["ph"] == "i"}
     assert by["from-worker"]["tid"] == names["engine-worker"]
     assert by["from-main"]["tid"] == names["MainThread"]
+
+
+def _spans(tr):
+    return {e["name"]: e["args"] for e in tr.to_dict()["traceEvents"]
+            if e["ph"] == "X"}
+
+
+def test_span_ids_and_parents_follow_each_threads_nesting():
+    vc = VirtualClock()
+    tr = obs_trace.Tracer(clock=vc, enabled=True)
+    tr.annotation = None
+    seen = {}
+
+    def work():
+        # another thread's stack: its spans do not nest under main's
+        with tr.span("worker.outer"):
+            with tr.span("worker.inner"):
+                pass
+
+    with tr.span("main.outer"):
+        t = threading.Thread(target=work, name="engine-worker")
+        t.start()
+        t.join()
+        with tr.span("main.inner") as inner:
+            seen["inner"] = inner.id
+            sib = tr.complete("main.done", 0.0, 1.0)
+        linked = tr.complete("linked", 0.0, 1.0, parent=seen["inner"])
+    a = _spans(tr)
+    ids = [v["span_id"] for v in a.values()]
+    assert len(set(ids)) == len(ids) == 6
+    assert "parent_id" not in a["main.outer"]
+    assert "parent_id" not in a["worker.outer"]
+    assert a["worker.inner"]["parent_id"] == a["worker.outer"]["span_id"]
+    assert a["main.inner"]["parent_id"] == a["main.outer"]["span_id"]
+    # complete(): the span open around the call, or the explicit parent
+    assert a["main.done"]["parent_id"] == seen["inner"]
+    assert a["main.done"]["span_id"] == sib
+    assert a["linked"]["parent_id"] == seen["inner"]
+    assert a["linked"]["span_id"] == linked
+
+
+def test_layout_id_is_the_root_spans_id_and_carried_below_it():
+    tr = obs_trace.Tracer(clock=VirtualClock(), enabled=True)
+    tr.annotation = None
+    with tr.span("before"):
+        pass
+    with tr.root("layout", n=4, m=3) as root:
+        with tr.span("coarsen"):
+            with tr.span("merger.dispatch"):
+                pass
+        group = tr.complete("refine.group", 0.0, 1.0)
+        tr.complete("refine", 0.0, 1.0, parent=group)
+    tr.complete("outside", 0.0, 1.0)
+    a = _spans(tr)
+    lid = a["layout"]["layout_id"]
+    assert lid == a["layout"]["span_id"] == root.id
+    assert a["layout"]["n"] == 4 and a["layout"]["m"] == 3
+    for name in ("coarsen", "merger.dispatch", "refine.group", "refine"):
+        assert a[name]["layout_id"] == lid, name
+    assert a["refine"]["parent_id"] == group
+    assert "layout_id" not in a["before"] and "layout_id" not in a["outside"]
+    # a second root starts a second layout
+    with tr.root("layout"):
+        with tr.span("place"):
+            pass
+    assert _spans(tr)["place"]["layout_id"] not in (None, lid)
+
+
+def test_reset_clears_the_id_counter():
+    tr = obs_trace.Tracer(clock=VirtualClock(), enabled=True)
+    tr.annotation = None
+
+    def one():
+        with tr.root("layout"):
+            with tr.span("refine.level"):
+                pass
+        return tr.json_bytes()
+
+    first = one()
+    assert len(tr) == 2
+    tr.reset()
+    assert len(tr) == 0
+    assert one() == first       # ids start again at 1
+    assert _spans(tr)["layout"]["span_id"] == 1
+
+
+def test_enabled_spans_mirror_into_the_profiler():
+    """While enabled, each span also opens the annotation hook under the
+    same name (jax.profiler.TraceAnnotation by default); complete(),
+    whose bounds are past, does not."""
+    tr = obs_trace.Tracer(clock=VirtualClock())
+    assert tr.annotation is None              # nothing imported until enable
+    tr.enable()
+    import jax
+    assert tr.annotation is jax.profiler.TraceAnnotation
+    log = []
+
+    class Hook:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            log.append(("open", self.name))
+
+        def __exit__(self, *exc):
+            log.append(("close", self.name))
+
+    tr.annotation = Hook
+    with tr.root("layout"):
+        with tr.span("refine.dispatch"):
+            pass
+    tr.complete("refine", 0.0, 1.0)
+    assert log == [("open", "layout"), ("open", "refine.dispatch"),
+                   ("close", "refine.dispatch"), ("close", "layout")]
+    tr.disable()
+    with tr.span("off"):
+        pass
+    assert len(log) == 4
+
+
+# -- one layout's spans, on XLA:CPU ----------------------------------------------
+
+@pytest.fixture(scope="module")
+def traced_layout():
+    """A 4,096-vertex delaunay graph laid out with the process tracer on:
+    three levels, the finest in neighbor mode (exact_threshold 1,024)."""
+    from repro.core import multigila_layout
+    e, n = G.delaunay(4096, 1)
+    cfg = LayoutConfig(exact_threshold=1024, seed=3)
+    modes = ("exact", "neighbor", "grid")
+    it = bucketing.REFINE_ITERATIONS
+    before = {m: it.value(engine="gila", mode=m) for m in modes}
+    tr = obs_trace.get_tracer()
+    tr.reset()
+    tr.enable()
+    try:
+        _, stats = multigila_layout(e, n, cfg)
+    finally:
+        tr.disable()
+    events = [x for x in tr.to_dict()["traceEvents"] if x["ph"] == "X"]
+    tr.reset()
+    counted = {m: it.value(engine="gila", mode=m) - before[m] for m in modes}
+    return dict(cfg=cfg, n=n, m=len(e), stats=stats, events=events,
+                counted=counted)
+
+
+def _named(events, name):
+    return [x for x in events if x["name"] == name]
+
+
+def test_each_level_has_one_dispatch_with_the_scheduled_iterations(
+        traced_layout):
+    t = traced_layout
+    cfg, stats, events = t["cfg"], t["stats"], t["events"]
+    levels = _named(events, "refine.level")
+    assert len(levels) == stats.levels == 3
+    modes = []
+    for lv in levels:
+        i = lv["args"]["level"]
+        n_i, m_i = stats.level_sizes[i]
+        want = make_schedule(i, stats.levels, n_i, m_i,
+                             exact_threshold=cfg.exact_threshold,
+                             grid_threshold=cfg.grid_threshold,
+                             coarsest_iters=cfg.coarsest_iters,
+                             finest_iters=cfg.finest_iters)
+        steps = [x for x in _named(events, "refine.dispatch")
+                 if x["args"]["parent_id"] == lv["args"]["span_id"]]
+        assert len(steps) == 1, (i, steps)
+        assert steps[0]["args"]["iters"] == want.iters, i
+        assert steps[0]["args"]["mode"] == want.mode, i
+        modes.append(want.mode)
+    assert sorted(modes) == ["exact", "exact", "neighbor"]
+
+
+def test_khop_build_nests_inside_the_neighbor_level(traced_layout):
+    events = traced_layout["events"]
+    (khop,) = _named(events, "refine.khop")
+    (level,) = [x for x in _named(events, "refine.level")
+                if x["args"]["span_id"] == khop["args"]["parent_id"]]
+    (step,) = [x for x in _named(events, "refine.dispatch")
+               if x["args"]["parent_id"] == level["args"]["span_id"]]
+    assert step["args"]["mode"] == "neighbor"
+    assert level["ts"] <= khop["ts"]
+    assert khop["ts"] + khop["dur"] <= step["ts"]
+    assert step["ts"] + step["dur"] <= level["ts"] + level["dur"]
+
+
+def test_iteration_counter_equals_the_spans_iterations(traced_layout):
+    events, counted = traced_layout["events"], traced_layout["counted"]
+    by_mode = {}
+    for x in _named(events, "refine.dispatch"):
+        by_mode[x["args"]["mode"]] = (by_mode.get(x["args"]["mode"], 0)
+                                      + x["args"]["iters"])
+    assert by_mode == {m: v for m, v in counted.items() if v}
+
+
+def test_every_span_of_the_layout_carries_its_layout_id(traced_layout):
+    t = traced_layout
+    events = t["events"]
+    (root,) = _named(events, "layout")
+    assert root["args"]["n"] == t["n"] and root["args"]["m"] == t["m"]
+    lid = root["args"]["layout_id"]
+    assert all(x["args"]["layout_id"] == lid for x in events)
+    names = {x["name"] for x in events}
+    for leaf in ("layout.components", "layout.prune", "layout.build",
+                 "coarsen.sort", "refine.stage", "layout.finish"):
+        assert leaf in names, leaf
+    # each span lies inside its parent
+    by_id = {x["args"]["span_id"]: x for x in events}
+    for x in events:
+        p = by_id.get(x["args"].get("parent_id"))
+        if p is not None:
+            assert p["ts"] <= x["ts"] and \
+                x["ts"] + x["dur"] <= p["ts"] + p["dur"], (x, p)
 
 
 # -- metrics registry ----------------------------------------------------------
@@ -146,31 +365,57 @@ def _path_request(n, seed=0):
     return bucketing.make_request(g, pos0, sched, seed), edges
 
 
+def _slots(name, bucket, axis):
+    return obs_metrics.REGISTRY.get(name).value(bucket=bucket, axis=axis)
+
+
 def test_padding_occupancy_gauges_match_hand_computed():
-    """Mixed-bucket 3-graph wave: two paths share the n64 lane bucket, the
-    third lands in n128; the gauges must equal true/padded exactly."""
+    """Mixed-bucket 3-graph wave, then the same pair again: two paths share
+    the n64 lane bucket, the third lands in n128. The true and padded slot
+    counters sum over dispatches, so the deltas between two readings give
+    that window's occupancy exactly."""
     (r1, e1), (r2, e2), (r3, e3) = (_path_request(10), _path_request(20),
                                     _path_request(100))
     assert bucketing.group_key(r1) == bucketing.group_key(r2)
     assert bucketing.group_key(r3) != bucketing.group_key(r1)
-
-    bucketing.refine_level_many([r1, r2], ideal_len=1.0, rep_const=1.0)
-    lanes = 8                                       # lane_bucket(2, 8)
     n_pad, m_pad = r1.g.n_pad, r1.g.m_pad
     assert (n_pad, m_pad) == (bucket_pad(10, 64), bucket_pad(2 * 9, 512))
-    occ_v = obs_metrics.REGISTRY.get("gila_wave_padding_occupancy_vertices")
-    occ_e = obs_metrics.REGISTRY.get("gila_wave_padding_occupancy_edges")
-    occ_l = obs_metrics.REGISTRY.get("gila_wave_lane_occupancy")
     b = f"n{n_pad}_e{m_pad}"
-    assert occ_v.value(bucket=b) == (10 + 20) / (lanes * n_pad)
-    assert occ_e.value(bucket=b) == (2 * 9 + 2 * 19) / (lanes * m_pad)
-    assert occ_l.value(bucket=b) == 2 / lanes
-
-    bucketing.refine_level_many([r3], ideal_len=1.0, rep_const=1.0)
     b3 = f"n{r3.g.n_pad}_e{r3.g.m_pad}"
     assert r3.g.n_pad == 128
-    assert occ_v.value(bucket=b3) == 100 / (8 * r3.g.n_pad)
-    assert occ_l.value(bucket=b3) == 1 / 8
+    axes = ("vertices", "edges", "lanes")
+
+    def read():
+        return {(bk, ax, kind): _slots(f"gila_wave_{kind}_slots_total",
+                                       bk, ax)
+                for bk in (b, b3) for ax in axes
+                for kind in ("true", "padded")}
+
+    lanes = 8                                       # lane_bucket(2, 8)
+    before = read()
+    bucketing.refine_level_many([r1, r2], ideal_len=1.0, rep_const=1.0)
+    bucketing.refine_level_many([r3], ideal_len=1.0, rep_const=1.0)
+    mid = read()
+    bucketing.refine_level_many([r1, r2], ideal_len=1.0, rep_const=1.0)
+    after = read()
+
+    def occ(lo, hi, bk, ax):
+        return ((hi[bk, ax, "true"] - lo[bk, ax, "true"])
+                / (hi[bk, ax, "padded"] - lo[bk, ax, "padded"]))
+
+    for lo, hi in ((before, mid), (mid, after), (before, after)):
+        assert occ(lo, hi, b, "vertices") == (10 + 20) / (lanes * n_pad)
+        assert occ(lo, hi, b, "edges") == (2 * 9 + 2 * 19) / (lanes * m_pad)
+        assert occ(lo, hi, b, "lanes") == 2 / lanes
+    assert occ(before, mid, b3, "vertices") == 100 / (8 * r3.g.n_pad)
+    assert occ(before, mid, b3, "edges") == 2 * 99 / (8 * r3.g.m_pad)
+    assert occ(before, mid, b3, "lanes") == 1 / 8
+    # the second wave of the pair left the n128 bucket's counters alone
+    for ax in axes:
+        for kind in ("true", "padded"):
+            assert after[b3, ax, kind] == mid[b3, ax, kind]
+    assert after[b, "vertices", "padded"] - before[b, "vertices", "padded"] \
+        == 2 * lanes * n_pad
 
 
 # -- sim trace replay determinism ----------------------------------------------
@@ -210,6 +455,14 @@ def test_sim_trace_replays_byte_identical():
                      "engine.expire", "wave", "refine.group", "refine",
                      "request", "engine.queue_depth"):
         assert expected in names, (expected, names)
+
+
+def test_sim_trace_links_lane_spans_to_their_group():
+    _, tr = _run_traced_sim()
+    evs = [e for e in tr.to_dict()["traceEvents"] if e["ph"] == "X"]
+    groups = {e["args"]["span_id"] for e in evs if e["name"] == "refine.group"}
+    lanes = [e for e in evs if e["name"] == "refine"]
+    assert lanes and all(e["args"]["parent_id"] in groups for e in lanes)
 
 
 def test_engine_stats_snapshot_against_scripted_trace():
@@ -267,9 +520,12 @@ def test_prometheus_endpoint_round_trip():
     # the acceptance series: cache hit/miss and padding occupancy
     assert samples["gila_compile_cache_misses_total"] >= 1
     assert "gila_compile_cache_hits_total" in samples
-    occ = {k: v for k, v in samples.items()
-           if k.startswith("gila_wave_padding_occupancy_vertices")}
-    assert occ and all(0.0 < v <= 1.0 for v in occ.values()), occ
+    padded = {k.split("{", 1)[1]: v for k, v in samples.items()
+              if k.startswith("gila_wave_padded_slots_total{")}
+    true = {k.split("{", 1)[1]: v for k, v in samples.items()
+            if k.startswith("gila_wave_true_slots_total{")}
+    assert padded and set(true) == set(padded), (true, padded)
+    assert all(0.0 < true[k] <= padded[k] for k in padded), (true, padded)
     assert any(k.startswith("gila_engine_requests_total") for k in samples)
     assert any(k.startswith("gila_request_latency_seconds_bucket")
                for k in samples)
