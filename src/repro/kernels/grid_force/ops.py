@@ -317,14 +317,20 @@ def grid_repulsion(pos, mass, vmask, C, L, min_dist, *,
         Q_out = jax.ops.segment_sum(w_out * q, cid, num_segments=nc + 1)
 
     # -- near field: exact within the 3×3 neighborhood ------------------------
-    # gathered straight into the kernel's lane-major planes (cells on lanes)
+    # gathered straight into the kernel's lane-major planes (cells on lanes).
+    # Column 4 of the neighbor table is offset (0, 0), the cell itself, so
+    # block 4 of the partner planes holds the cell's own bucket: the rows are
+    # a static slice of the one gather. (A separate ``xyw_p[:2, rows.T]``
+    # pairs the row slice with a column index; the TPU compiler lowers that
+    # point gather to one loop trip per bucket slot.)
     with jax.named_scope("grid.near"):
         table = jnp.asarray(_neighbor_table(G))             # [nc+1, 9]
         xyw_p = jnp.pad(jnp.concatenate([pos.T, w[None]], axis=0),
                         ((0, 0), (0, 1)))                   # [3, n+1]
         rows_idx = bucket[:nc]                              # [nc, cap]
         nbr_bucket = bucket[table[:nc]].reshape(nc, 9 * cap)
-        near = near_field(xyw_p[:2, rows_idx.T], xyw_p[:, nbr_bucket.T],
+        partners = xyw_p[:, nbr_bucket.T]                   # [3, 9cap, nc]
+        near = near_field(partners[:2, 4 * cap:5 * cap], partners,
                           C, L, min_dist, backend=mode)     # [2, cap, nc]
         f_near = jnp.zeros((n + 1, 2), jnp.float32).at[
             rows_idx.reshape(-1)].set(

@@ -2,6 +2,7 @@
 approximation-error bounds vs the all-pairs oracle, and schedule wiring."""
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 
 from repro.kernels.grid_force.ref import grid_near_ref, grid_far_ref
@@ -199,3 +200,103 @@ def test_choose_grid_scaling():
     G5, _ = choose_grid(50_000)
     G1m, _ = choose_grid(1_000_000)
     assert G1m > G5                   # finer grids for bigger levels
+
+
+def _centre_block_input(kind):
+    """A random input with 5% of vertices masked, or Gaussian clusters that
+    overflow their cells' buckets."""
+    rng = np.random.default_rng(11)
+    if kind == "random":
+        pos_np = rng.random((2500, 2)) * 12
+    else:
+        pos_np = np.concatenate([rng.normal(0, 0.5, (900, 2)),
+                                 rng.normal(6, 0.4, (900, 2)),
+                                 rng.random((500, 2)) * 10 - 2])
+    n = len(pos_np)
+    pos = jnp.asarray(pos_np, jnp.float32)
+    mass = jnp.asarray(rng.random(n) + 0.5, jnp.float32)
+    vmask = jnp.asarray(rng.random(n) > 0.05)
+    return pos, mass, vmask
+
+
+def _parent_grid_repulsion(pos, mass, vmask, C, L, md, *, grid_dim,
+                           cell_cap):
+    """``grid_repulsion`` as it was with two gathers: the rows fetched by
+    their own ``xyw_p[:2, bucket[:nc].T]``, apart from the partner planes."""
+    from repro.kernels.grid_force.ops import (
+        bin_vertices, cell_aggregates, cell_centers, neighbor_table,
+        near_field, far_all_cells, far_corrections)
+    from repro.kernels import backend
+    mode = backend()
+    n = pos.shape[0]
+    G, cap = grid_dim, cell_cap
+    nc = G * G
+    w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)
+    cid, bucket, inb = bin_vertices(pos, vmask, G, cap)
+    M_full, S_full, mu_full = cell_aggregates(pos, w, cid, nc)
+    w_out = jnp.where(inb, 0.0, w)
+    M_out, S_out, _ = cell_aggregates(pos, w_out, cid, nc)
+    centers = cell_centers(pos, vmask, G)
+    q = jnp.sum((pos - centers[cid]) ** 2, axis=1)
+    Q_full = jax.ops.segment_sum(w * q, cid, num_segments=nc + 1)
+    Q_out = jax.ops.segment_sum(w_out * q, cid, num_segments=nc + 1)
+    table = jnp.asarray(neighbor_table(G))
+    xyw_p = jnp.pad(jnp.concatenate([pos.T, w[None]], axis=0),
+                    ((0, 0), (0, 1)))
+    rows_idx = bucket[:nc]
+    nbr_bucket = bucket[table[:nc]].reshape(nc, 9 * cap)
+    near = near_field(xyw_p[:2, rows_idx.T], xyw_p[:, nbr_bucket.T],
+                      C, L, md, backend=mode)
+    f_near = jnp.zeros((n + 1, 2), jnp.float32).at[
+        rows_idx.reshape(-1)].set(
+        jnp.transpose(near, (2, 1, 0)).reshape(-1, 2))[:n]
+    cell_xyw = jnp.concatenate([mu_full[:nc], M_full[:nc, None]], axis=1)
+    f_far = far_all_cells(pos, cell_xyw, C, L, md, mode)
+    f_far += far_corrections(pos, w_out, cid, inb,
+                             M_full, S_full, Q_full, M_out, S_out, Q_out,
+                             C, L, md, grid_dim=G, centers=centers)
+    return jnp.where(vmask[:, None], f_near + f_far, 0.0)
+
+
+@pytest.mark.parametrize("kind", ["random", "cluster"])
+def test_neighbor_table_centre_is_the_cell(kind):
+    from repro.kernels.grid_force.ops import neighbor_table
+    pos, _, _ = _centre_block_input(kind)
+    G, _ = choose_grid(pos.shape[0])
+    np.testing.assert_array_equal(neighbor_table(G)[:G * G, 4],
+                                  np.arange(G * G))
+
+
+@pytest.mark.parametrize("kind", ["random", "cluster"])
+def test_partner_centre_block_is_the_rows(kind):
+    """Block 4 of the gathered partner planes is the cells' own buckets,
+    sentinel slots and overflowing cells included."""
+    from repro.kernels.grid_force.ops import bin_vertices, neighbor_table
+    pos, mass, vmask = _centre_block_input(kind)
+    G, cap = choose_grid(pos.shape[0])
+    nc = G * G
+    _, bucket, inb = bin_vertices(pos, vmask, G, cap)
+    if kind == "cluster":
+        assert not np.asarray(inb)[np.asarray(vmask)].all()  # overflow
+    w = jnp.where(vmask, mass, 0.0)
+    xyw_p = jnp.pad(jnp.concatenate([pos.T, w[None]], axis=0),
+                    ((0, 0), (0, 1)))
+    nbr_bucket = bucket[jnp.asarray(neighbor_table(G))[:nc]].reshape(
+        nc, 9 * cap)
+    partners = xyw_p[:, nbr_bucket.T]
+    np.testing.assert_array_equal(np.asarray(partners[:2, 4 * cap:5 * cap]),
+                                  np.asarray(xyw_p[:2, bucket[:nc].T]))
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+@pytest.mark.parametrize("kind", ["random", "cluster"])
+def test_grid_repulsion_matches_two_gather_form(kind, backend, monkeypatch):
+    """Taking the rows from the partner planes changes no bit of the
+    forces, in either CPU backend."""
+    monkeypatch.setenv("REPRO_PALLAS", backend)
+    pos, mass, vmask = _centre_block_input(kind)
+    G, cap = choose_grid(pos.shape[0])
+    args = (pos, mass, vmask, 1.2, 0.9, 1e-2)
+    got = grid_repulsion(*args, grid_dim=G, cell_cap=cap)
+    want = _parent_grid_repulsion(*args, grid_dim=G, cell_cap=cap)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

@@ -151,3 +151,26 @@ def test_sharded_steps_compile_at_paper_scale(topo, S, monkeypatch, mode,
     assert used < 16 << 30, used
     if mode == "grid":
         assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_grid_repulsion_has_no_row_gather_loop(S, monkeypatch):
+    """The grid op at n = 16,384 (G 37, cap 48): the near field's rows come
+    from the partner planes, so no gather of the bucket slots' x, y is
+    lowered to a loop of one trip per slot (a ``while`` carrying a
+    ``s32[nc*cap,2]`` point index, under ``grid.near``)."""
+    from repro.kernels.grid_force.ops import choose_grid, grid_repulsion
+    monkeypatch.setenv("REPRO_PALLAS", "pallas")
+    n = 16384
+    G, cap = choose_grid(n)
+    assert (G, cap) == (37, 48)
+    text = _compile_text(
+        lambda p, m, v: grid_repulsion(p, m, v, 1.0, 1.0, 1e-3,
+                                       grid_dim=G, cell_cap=cap),
+        S(n, 2), S(n), S(n, dt=bool))
+    assert "tpu_custom_call" in text
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert loops
+    near = [ln for ln in loops if "grid.near/gather" in ln]
+    assert not near, near
+    point_index = f"s32[{G * G * cap},2]"
+    assert not [ln for ln in loops if point_index in ln], point_index
