@@ -1,7 +1,7 @@
 #!/usr/bin/env python3
 """Prove that the layout system runs on the chip, end to end.
 
-    python chip_smoke.py              # one TPU chip: phases k, a, b, c
+    python chip_smoke.py              # one TPU chip: phases k, a, b, c, s
     python chip_smoke.py --chips 4    # four chips: the sharded driver only
 
 All phases run in this one process, which owns the chip:
@@ -22,6 +22,13 @@ All phases run in this one process, which owns the chip:
               dedicated driver's layout of the same graph and seed.
   c  tiles    the tile pyramid of (a)'s export answers 16 batched viewport
               queries, each equal, bit for bit, to ``reference_resolve``.
+  s  stress   a 131,072-vertex Delaunay graph through ``multigila_layout``
+              with the maxent-stress engine; from the drawing each level's
+              refine step produced, the program's cached stress step at
+              its grid level and its largest exact level after 1 and 5
+              iterations, and at its neighbor level after 1, against the
+              plain reference (``core/stress_ref.py``) within
+              ``STRESS_TOL``.
 
 ``--chips 4`` lays grid_120x120 out (see ``SHARDED_SIDE``) with
 ``driver="multigila_dist"`` on a (4, 1) (data, model) mesh and with
@@ -82,6 +89,25 @@ STEP_TOL = 1e-3
 #: (NELD 0.0117, stress 0.0178); the un-psum'd grid sums above give stress
 #: 0.54.
 DIST_BOUND = {"neld": 0.035, "stress": 0.055}
+
+
+#: phase (s): the program's cached maxent-stress step against the plain
+#: reference from the same positions, after each count of iterations in
+#: ``STRESS_ITERS``: the largest displacement difference of a vertex, as a
+#: fraction of the level's step clamp ``temp0``. The two differ in the order
+#: of their float sums, in the kernels (compiled Pallas tiles against the
+#: jnp oracles) and, at the grid level, in the approximation's arrangement
+#: (the reference writes it out from its definition): at 131,072 vertices
+#: on the chip, from settled drawings, one iteration read at most 6.5e-6 at
+#: every level and five at most 1.2e-4 at the grid and exact levels (two
+#: graphs), while positions cast to bfloat16 inside the step read 0.074 or
+#: more. Five iterations at the neighbor level read up to 3.2e-3: its k-hop
+#: lists keep near-coincident pairs, whose repulsion turns rounding into
+#: motion (the program against itself from positions a few ulps apart reads
+#: 0.08 there), so that level is compared after one. PERF.md gives every
+#: reading.
+STRESS_TOL = 1e-3
+STRESS_ITERS = {"grid": (1, 5), "exact": (1, 5), "neighbor": (1,)}
 
 
 def _refuse(msg: str) -> None:
@@ -405,6 +431,92 @@ def phase_tiles(exp, queries: int = 16, seed: int = 0):
           wall_s=round(t_cold + t_warm, 3), parity="bit-exact")
 
 
+# -- phase s: the maxent-stress step against its plain reference ----------------
+
+def capture_levels(edges: np.ndarray, n: int, cfg, modes) -> dict:
+    """Lay the graph out with ``cfg`` and keep, for each mode in ``modes``,
+    the largest level refined in it: ``{mode: (g, pos, sched, kw)}``, the
+    level's padded graph, the drawing its refine step produced (on the
+    host), its schedule and the step's keyword arguments.
+
+    The comparison starts from that settled drawing, not from the level's
+    start: there the placer leaves vertices on top of one another (10 to
+    850 per level at 16,384 vertices), the direction of their mutual
+    repulsion is set by rounding, and five iterations from there read up
+    to 1.33 ``temp0`` apart on the chip between any two sums of another
+    order (PERF.md)."""
+    from repro.core import bucketing, multigila_layout
+
+    kept, one = {}, bucketing.refine_level
+
+    def keep(g, pos0, sched, **kw):
+        out = one(g, pos0, sched, **kw)
+        if sched.mode in modes and (sched.mode not in kept
+                                    or g.n > kept[sched.mode][0].n):
+            kept[sched.mode] = (g, np.asarray(out), sched, kw)
+        return out
+
+    bucketing.refine_level = keep
+    try:
+        multigila_layout(edges, n, cfg)
+    finally:
+        bucketing.refine_level = one
+    return kept
+
+
+def stress_step_errors(levels: dict, iters=STRESS_ITERS,
+                       build=None) -> dict:
+    """``{"<mode>.<iters>": error}``: each captured level's cached stress
+    step against ``core/stress_ref.py`` from the same positions, under the
+    level's schedule cut to each of ``iters[mode]`` iterations: the largest
+    displacement difference over the level's ``temp0``. ``build`` (an
+    engine's ``build_refine``) replaces the cached program."""
+    import jax.numpy as jnp
+    from repro.core import bucketing, stress_ref
+    from repro.core.engine import get_engine
+
+    eng = get_engine("stress")
+    errs = {}
+    for mode, (g, pos, sched, kw) in levels.items():
+        valid = np.asarray(g.vmask)
+        for it in iters[mode]:
+            s = dataclasses.replace(sched, iters=it)
+            nbrs = eng.init_state(g, s, kw["seed"])
+            _, fn, _, args = bucketing.cached_refine(
+                g, pos, s, *nbrs, ideal_len=kw["ideal_len"],
+                rep_const=kw["rep_const"])
+            if build is not None:
+                fn = build(s.mode, s.grid_dim, s.cell_cap)
+            got = np.asarray(fn(*args))
+            want = np.asarray(stress_ref.refine(
+                g, jnp.asarray(pos), s, *nbrs, ideal_len=kw["ideal_len"],
+                rep_const=kw["rep_const"]))
+            errs[f"{mode}.{it}"] = float(
+                np.abs(got - want)[valid].max() / sched.temp0)
+    return errs
+
+
+def phase_stress(n: int = 1 << 17, cfg=None, modes=tuple(STRESS_ITERS)):
+    """(s): the stress step of a Delaunay graph's levels vs the reference."""
+    from repro.core import LayoutConfig
+    from repro.graphs import generators as G
+
+    cfg = cfg or LayoutConfig(engine="stress")
+    edges, n = G.delaunay(n, 17)
+    t0 = time.perf_counter()
+    levels = capture_levels(edges, n, cfg, modes)
+    assert set(levels) == set(modes), (sorted(levels), modes)
+    errs = stress_step_errors(levels)
+    assert all(e <= STRESS_TOL for e in errs.values()), errs
+    _line("s stress", graph=f"delaunay_{n}", m=len(edges),
+          levels=json.dumps({m: [lv[0].n, lv[2].grid_dim, lv[2].cell_cap]
+                             for m, lv in levels.items()},
+                            separators=(",", ":")),
+          step_err=json.dumps(errs, separators=(",", ":")),
+          stress_tol=STRESS_TOL, wall_s=round(time.perf_counter() - t0, 3))
+    return errs
+
+
 # -- --chips 4: the sharded driver ----------------------------------------------
 
 def _one_device_superstep(g, pos0, sched, *, ideal_len: float,
@@ -530,6 +642,7 @@ def main(argv=None) -> None:
         exp = phase_layout()
         phase_service()
         phase_tiles(exp)
+        phase_stress()
     import jax
     d = jax.devices()[0]
     print(json.dumps({"ok": True, "device": {"platform": d.platform,

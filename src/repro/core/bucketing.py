@@ -301,7 +301,7 @@ def refine_level(g: PaddedGraph, pos0, sched, *, ideal_len: float,
     t0 = time.perf_counter()
     with obs_trace.span("refine.dispatch", cat="device", key=key,
                         fresh=fresh, mode=sched.mode, engine=sched.engine,
-                        iters=sched.iters):
+                        iters=sched.iters, **eng.span_args(sched)):
         pos = fn(*args)
         pos.block_until_ready()
     PHASES.add("compile" if fresh else "refine", time.perf_counter() - t0)
@@ -515,13 +515,15 @@ def refine_level_many(reqs: list[RefineRequest], *, ideal_len: float,
         reqs, nbrs, ideal_len=ideal_len, rep_const=rep_const,
         min_dist=min_dist, lanes_min=lanes_min)
     # span brackets the existing dispatch + sync only (no added syncs);
-    # every lane rides the loop's max_iters trips (idle past its own budget)
-    iters = max(r.sched.iters for r in reqs)
-    engine = reqs[0].sched.engine
+    # every lane rides the loop's max_iters trips (idle past its own budget);
+    # the annealing recorded is that of the lane whose budget sets them
+    lead = max(reqs, key=lambda r: r.sched.iters).sched
+    iters = lead.iters
+    engine = lead.engine
     t0 = time.perf_counter()
     with obs_trace.span("refine_many.dispatch", cat="device", key=key,
                         fresh=fresh, lanes=len(reqs), engine=engine,
-                        mode=mode, iters=iters):
+                        mode=mode, iters=iters, **eng.span_args(lead)):
         out = fn(*args)
         out.block_until_ready()
     PHASES.add("compile" if fresh else "refine", time.perf_counter() - t0)

@@ -59,6 +59,12 @@ class RefinementEngine:
         """The per-lane annealing scalars for one level, length ``sched_k``."""
         raise NotImplementedError
 
+    def span_args(self, sched) -> dict:
+        """Arguments the refine dispatch spans record beside ``mode`` and
+        ``iters``: the annealing a step of ``sched`` runs beyond the
+        cooling (none for GiLA)."""
+        return {}
+
     def tune(self, sched):
         """Hook over a freshly built ``LevelSchedule``; default: unchanged."""
         return sched

@@ -64,42 +64,52 @@ def stress_terms(g: PaddedGraph, L):
     """Position-independent per-edge terms, hoisted out of the iteration
     loop: target lengths ℓ_e, weights w_e = 1/ℓ_e² (0 on padding), and the
     per-vertex weight sum ρ."""
-    ell = jnp.maximum(g.ewt, 1e-6) * L
-    we = jnp.where(g.emask, 1.0 / (ell * ell), 0.0)
-    rho = jax.ops.segment_sum(we, g.dst, num_segments=g.n_pad + 1)[:g.n_pad]
-    return ell, we, rho
+    with jax.named_scope("stress.terms"):
+        ell = jnp.maximum(g.ewt, 1e-6) * L
+        we = jnp.where(g.emask, 1.0 / (ell * ell), 0.0)
+        rho = jax.ops.segment_sum(we, g.dst,
+                                  num_segments=g.n_pad + 1)[:g.n_pad]
+        return ell, we, rho
 
 
 def stress_iteration(g: PaddedGraph, pos, nbr_idx, nbr_mask, ell, we, rho,
                      params_arr, temp, alpha, *, mode: str, grid_dim: int = 0,
                      cell_cap: int = 0):
     """One maxent-stress Jacobi iteration (shared by ``stress_layout`` and
-    the cached builders' per-lane arithmetic contract)."""
+    the cached builders' per-lane arithmetic contract).
+
+    The named scopes (``stress.targets``, ``stress.entropy``,
+    ``stress.move``; ``stress.terms`` in ``stress_terms``) only label the
+    compiled operations, so a device profile names each stage; the
+    kernels' own ``grid.*`` scopes nest inside ``stress.entropy``."""
     C, L, md = params_arr[0], params_arr[1], params_arr[2]
     n_pad = g.n_pad
-    ps = edge_gather(g, pos)                        # source endpoint per edge
-    pd = pos[jnp.clip(g.dst, 0, n_pad - 1)]
-    delta = pd - ps
-    dist = jnp.sqrt(jnp.sum(delta * delta, axis=1) + md ** 2)
-    tgt = ps + delta / dist[:, None] * ell[:, None]
-    vec = jnp.where(g.emask[:, None], we[:, None] * tgt, 0.0)
-    num = jax.ops.segment_sum(vec, g.dst, num_segments=n_pad + 1)[:n_pad]
-    ca = alpha * C                                  # entropy strength α·C
-    if mode == "exact":
-        rep = gila._repulsion_exact(pos, g.mass, g.vmask, ca, L, md)
-    elif mode == "grid":
-        rep = gila._repulsion_grid(pos, g.mass, g.vmask, ca, L, md,
-                                   grid_dim, cell_cap)
-    else:
-        rep = gila._repulsion_neighbors(pos, g.mass, nbr_idx, nbr_mask,
-                                        g.vmask, ca, L, md)
-    new = (num + rep) / jnp.maximum(rho, 1e-12)[:, None]
-    new = jnp.where(rho[:, None] > 0, new, pos)     # no edges → stay put
-    d = new - pos
-    norm = jnp.sqrt(jnp.sum(d * d, axis=1) + 1e-12)
-    step = jnp.minimum(norm, temp)                  # GiLA's cooling clamp
-    pos = pos + d / norm[:, None] * step[:, None]
-    return jnp.where(g.vmask[:, None], pos, 0.0)
+    with jax.named_scope("stress.targets"):
+        ps = edge_gather(g, pos)                    # source endpoint per edge
+        pd = pos[jnp.clip(g.dst, 0, n_pad - 1)]
+        delta = pd - ps
+        dist = jnp.sqrt(jnp.sum(delta * delta, axis=1) + md ** 2)
+        tgt = ps + delta / dist[:, None] * ell[:, None]
+        vec = jnp.where(g.emask[:, None], we[:, None] * tgt, 0.0)
+        num = jax.ops.segment_sum(vec, g.dst, num_segments=n_pad + 1)[:n_pad]
+    with jax.named_scope("stress.entropy"):
+        ca = alpha * C                              # entropy strength α·C
+        if mode == "exact":
+            rep = gila._repulsion_exact(pos, g.mass, g.vmask, ca, L, md)
+        elif mode == "grid":
+            rep = gila._repulsion_grid(pos, g.mass, g.vmask, ca, L, md,
+                                       grid_dim, cell_cap)
+        else:
+            rep = gila._repulsion_neighbors(pos, g.mass, nbr_idx, nbr_mask,
+                                            g.vmask, ca, L, md)
+    with jax.named_scope("stress.move"):
+        new = (num + rep) / jnp.maximum(rho, 1e-12)[:, None]
+        new = jnp.where(rho[:, None] > 0, new, pos)  # no edges → stay put
+        d = new - pos
+        norm = jnp.sqrt(jnp.sum(d * d, axis=1) + 1e-12)
+        step = jnp.minimum(norm, temp)              # GiLA's cooling clamp
+        pos = pos + d / norm[:, None] * step[:, None]
+        return jnp.where(g.vmask[:, None], pos, 0.0)
 
 
 @partial(jax.jit, static_argnames=("mode", "iters", "grid_dim", "cell_cap",
@@ -139,6 +149,10 @@ class StressEngine(engine_mod.RefinementEngine):
     def lane_schedule(self, sched) -> tuple:
         a0, ad = alpha_schedule(sched.iters)
         return (sched.temp0, sched.temp_decay, a0, ad)
+
+    def span_args(self, sched) -> dict:
+        a0, ad = alpha_schedule(sched.iters)
+        return dict(alpha0=a0, alpha_decay=ad)
 
     def build_refine(self, mode: str, grid_dim: int, cell_cap: int):
         """Compile-cached per-level stress loop: iteration count and the
@@ -192,8 +206,9 @@ class StressEngine(engine_mod.RefinementEngine):
             flat_dst = (dst + offs).reshape(-1)
             flat_src = src + offs
             flat_dst_clip = jnp.clip(dst, 0, n_pad - 1) + offs
-            ell = jnp.maximum(ewt, 1e-6) * L                     # [B, m_pad]
-            we = jnp.where(emask, 1.0 / (ell * ell), 0.0)
+            with jax.named_scope("stress.terms"):
+                ell = jnp.maximum(ewt, 1e-6) * L                 # [B, m_pad]
+                we = jnp.where(emask, 1.0 / (ell * ell), 0.0)
             flat_inc = inc + (jnp.arange(B, dtype=jnp.int32)
                               * (m_pad + 1))[:, None, None]
 
@@ -218,7 +233,8 @@ class StressEngine(engine_mod.RefinementEngine):
                     num_segments=B * (n_pad + 1))
                 return out.reshape((B, n_pad + 1) + x.shape[2:])[:, :n_pad]
 
-            rho = agg_edges(we)                                  # [B, n_pad]
+            with jax.named_scope("stress.terms"):
+                rho = agg_edges(we)                              # [B, n_pad]
 
             def stress_num(pos):
                 flat = flat_pos(pos)
@@ -252,15 +268,19 @@ class StressEngine(engine_mod.RefinementEngine):
 
             def body(i, carry):
                 pos, temp, al = carry
-                num = stress_num(pos)
-                rep = repulsion(pos, al * C)
-                new = (num + rep) / jnp.maximum(rho, 1e-12)[..., None]
-                new = jnp.where(rho[..., None] > 0, new, pos)
-                d = new - pos
-                norm = jnp.sqrt(jnp.sum(d * d, axis=2) + 1e-12)
-                step = jnp.minimum(norm, temp[:, None])
-                new = pos + d / norm[..., None] * step[..., None]
-                new = jnp.where(vmask[..., None], new, 0.0)
+                # the single-graph step's scopes (stress_iteration)
+                with jax.named_scope("stress.targets"):
+                    num = stress_num(pos)
+                with jax.named_scope("stress.entropy"):
+                    rep = repulsion(pos, al * C)
+                with jax.named_scope("stress.move"):
+                    new = (num + rep) / jnp.maximum(rho, 1e-12)[..., None]
+                    new = jnp.where(rho[..., None] > 0, new, pos)
+                    d = new - pos
+                    norm = jnp.sqrt(jnp.sum(d * d, axis=2) + 1e-12)
+                    step = jnp.minimum(norm, temp[:, None])
+                    new = pos + d / norm[..., None] * step[..., None]
+                    new = jnp.where(vmask[..., None], new, 0.0)
                 live = i < iters
                 return (jnp.where(live[:, None, None], new, pos),
                         jnp.where(live, temp * temp_decay, temp),

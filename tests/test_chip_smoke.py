@@ -51,6 +51,19 @@ def test_layout_service_and_tile_phases_tiny(cs, capsys):
     assert '"grid":1' in out and "parity=bit-exact" in out
 
 
+def test_stress_phase_tiny(cs, capsys):
+    """(s) at a size whose finest level runs the grid mode: the cached
+    stress step at the grid, neighbor and exact levels against the
+    reference, through interpret-mode kernels. Its levels hold 600, 63
+    and 7 vertices, so an exact threshold of 32 puts the middle one in the
+    neighbor mode."""
+    cfg = LayoutConfig(engine="stress", **dict(TINY, exact_threshold=32))
+    errs = cs.phase_stress(600, cfg)
+    assert set(errs) == {f"{m}.{i}" for m, its in cs.STRESS_ITERS.items()
+                         for i in its}
+    assert "[s stress]" in capsys.readouterr().out
+
+
 def test_sharded_phase_tiny_on_four_virtual_devices():
     """--chips 4 path on four virtual CPU devices: arrays spread over all
     four, quality within the stated bound of the one-device driver. The

@@ -208,16 +208,16 @@ def test_enabled_spans_mirror_into_the_profiler():
 
 # -- one layout's spans, on XLA:CPU ----------------------------------------------
 
-@pytest.fixture(scope="module")
-def traced_layout():
-    """A 4,096-vertex delaunay graph laid out with the process tracer on:
-    three levels, the finest in neighbor mode (exact_threshold 1,024)."""
+def _trace_layout(engine: str) -> dict:
+    """A 4,096-vertex delaunay graph laid out by ``engine`` with the process
+    tracer on: three levels, the finest in neighbor mode (exact_threshold
+    1,024); the spans, and the iterations counter's increase per mode."""
     from repro.core import multigila_layout
     e, n = G.delaunay(4096, 1)
-    cfg = LayoutConfig(exact_threshold=1024, seed=3)
+    cfg = LayoutConfig(exact_threshold=1024, seed=3, engine=engine)
     modes = ("exact", "neighbor", "grid")
     it = bucketing.REFINE_ITERATIONS
-    before = {m: it.value(engine="gila", mode=m) for m in modes}
+    before = {m: it.value(engine=engine, mode=m) for m in modes}
     tr = obs_trace.get_tracer()
     tr.reset()
     tr.enable()
@@ -227,9 +227,19 @@ def traced_layout():
         tr.disable()
     events = [x for x in tr.to_dict()["traceEvents"] if x["ph"] == "X"]
     tr.reset()
-    counted = {m: it.value(engine="gila", mode=m) - before[m] for m in modes}
+    counted = {m: it.value(engine=engine, mode=m) - before[m] for m in modes}
     return dict(cfg=cfg, n=n, m=len(e), stats=stats, events=events,
                 counted=counted)
+
+
+@pytest.fixture(scope="module")
+def traced_layout():
+    return _trace_layout("gila")
+
+
+@pytest.fixture(scope="module")
+def traced_stress_layout():
+    return _trace_layout("stress")
 
 
 def _named(events, name):
@@ -280,6 +290,52 @@ def test_iteration_counter_equals_the_spans_iterations(traced_layout):
         by_mode[x["args"]["mode"]] = (by_mode.get(x["args"]["mode"], 0)
                                       + x["args"]["iters"])
     assert by_mode == {m: v for m, v in counted.items() if v}
+
+
+def test_stress_dispatches_record_the_schedule_and_the_annealing(
+        traced_stress_layout):
+    """Under the stress engine each level has one ``refine.dispatch`` with
+    ``engine`` stress, the ``iters`` of ``make_schedule`` and the
+    ``alpha0``/``alpha_decay`` of ``alpha_schedule(iters)``."""
+    from repro.core.stress import alpha_schedule
+    t = traced_stress_layout
+    cfg, stats, events = t["cfg"], t["stats"], t["events"]
+    levels = _named(events, "refine.level")
+    assert len(levels) == stats.levels == 3
+    for lv in levels:
+        i = lv["args"]["level"]
+        n_i, m_i = stats.level_sizes[i]
+        want = make_schedule(i, stats.levels, n_i, m_i,
+                             exact_threshold=cfg.exact_threshold,
+                             grid_threshold=cfg.grid_threshold,
+                             coarsest_iters=cfg.coarsest_iters,
+                             finest_iters=cfg.finest_iters,
+                             engine="stress")
+        (step,) = [x for x in _named(events, "refine.dispatch")
+                   if x["args"]["parent_id"] == lv["args"]["span_id"]]
+        args = step["args"]
+        assert (args["engine"], args["mode"], args["iters"]) == (
+            "stress", want.mode, want.iters), i
+        assert (args["alpha0"], args["alpha_decay"]) == alpha_schedule(
+            want.iters), i
+
+
+def test_stress_iteration_counter_equals_the_spans_iterations(
+        traced_stress_layout):
+    events, counted = (traced_stress_layout["events"],
+                       traced_stress_layout["counted"])
+    by_mode = {}
+    for x in _named(events, "refine.dispatch"):
+        assert x["args"]["engine"] == "stress"
+        by_mode[x["args"]["mode"]] = (by_mode.get(x["args"]["mode"], 0)
+                                      + x["args"]["iters"])
+    assert by_mode == {m: v for m, v in counted.items() if v}
+    assert set(by_mode) == {"exact", "neighbor"}
+
+
+def test_a_gila_dispatch_records_no_annealing(traced_layout):
+    for x in _named(traced_layout["events"], "refine.dispatch"):
+        assert "alpha0" not in x["args"] and "alpha_decay" not in x["args"]
 
 
 def test_every_span_of_the_layout_carries_its_layout_id(traced_layout):

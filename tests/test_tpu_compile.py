@@ -174,3 +174,29 @@ def test_grid_repulsion_has_no_row_gather_loop(S, monkeypatch):
     assert not near, near
     point_index = f"s32[{G * G * cap},2]"
     assert not [ln for ln in loops if point_index in ln], point_index
+
+
+def test_stress_grid_step_at_the_cells_finest_level(S, monkeypatch):
+    """The maxent-stress engine's cached grid step at the finest level of
+    ``delaunay_n17_stress.offline`` (131,072 vertices, 1,048,576 half-edge
+    slots, G 105, cap 48): both Pallas kernels, and no gather of the bucket
+    slots' x, y lowered to a loop of one trip per slot (a ``while`` with a
+    ``s32[nc*cap,2]`` point index, or one under ``grid.near``)."""
+    from repro.core import engine
+    from repro.kernels.grid_force.ops import choose_grid
+    monkeypatch.setenv("REPRO_PALLAS", "pallas")
+    n, m, K = 131072, 1 << 20, 1
+    G, cap = choose_grid(n)
+    assert (G, cap) == (105, 48)
+    step = engine.get_engine("stress").build_refine("grid", G, cap)
+    i32 = jnp.int32
+    text = step.lower(
+        S(n, 2), S(m, dt=i32), S(m, dt=i32), S(n, dt=bool), S(m, dt=bool),
+        S(n), S(m), S(n, K, dt=i32), S(n, K, dt=bool), S(dt=i32), S(4),
+        S(3)).compile().as_text()
+    assert "%nbody_pallas" in text and "%neighbor_pallas" in text
+    loops = [ln for ln in text.splitlines() if " while(" in ln]
+    assert loops
+    assert not [ln for ln in loops if "grid.near/gather" in ln]
+    point_index = f"s32[{G * G * cap},2]"
+    assert not [ln for ln in loops if point_index in ln], point_index
