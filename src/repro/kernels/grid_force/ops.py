@@ -155,19 +155,20 @@ def _cell_aggregates(pos, w, cid, nc: int):
 
 
 def _agg_field_9(pos, mu9, m9, C, L, md, r9=None):
-    """Aggregate force field of each vertex's 9 gathered cells:
-    pos [n, 2], mu9 [n, 9, 2], m9 [n, 9] → [n, 2]. ``r9`` optionally
+    """Aggregate force field of each vertex's 9 gathered cells, on
+    lane-major planes with the vertices on lanes: pos [2, n], mu9
+    [2, 9, n], m9 [9, n] → [2, n]. ``r9`` [9, n] optionally
     Plummer-softens each aggregate by its RMS radius (a point mass cannot
     faithfully stand in for a spread-out set at near range — softening by
     the set's extent bounds the spurious 1/d² spike)."""
-    dx = pos[:, 0][:, None] - mu9[..., 0]
-    dy = pos[:, 1][:, None] - mu9[..., 1]
+    dx = pos[0][None, :] - mu9[0]
+    dy = pos[1][None, :] - mu9[1]
     d2 = dx * dx + dy * dy + md * md
     if r9 is not None:
         d2 = d2 + r9 * r9
     inv = (C * L * L) * m9 / d2
-    return jnp.stack([jnp.sum(dx * inv, axis=1),
-                      jnp.sum(dy * inv, axis=1)], axis=1)
+    return jnp.stack([jnp.sum(dx * inv, axis=0),
+                      jnp.sum(dy * inv, axis=0)])
 
 
 def _far_all_cells(pos, cell_xyw, C, L, md, mode: str):
@@ -252,8 +253,6 @@ def far_corrections(pos, w_out, cid, inb,
     Shared verbatim between ``grid_repulsion`` and the sharded SPMD body in
     ``core/distributed.py`` (there the raw sums arrive via psum).
     """
-    G = grid_dim
-    nc = G * G
     mu_full = S_full / jnp.maximum(M_full, _EPS)[:, None]
     mu_out = S_out / jnp.maximum(M_out, _EPS)[:, None]
     r_out = _rms(Q_out, M_out, S_out, centers)
@@ -262,27 +261,39 @@ def far_corrections(pos, w_out, cid, inb,
     mu_in = S_in / jnp.maximum(M_in, _EPS)[:, None]
     r_in = _rms(Q_full - Q_out, M_in, S_in, centers)
 
-    table = jnp.asarray(_neighbor_table(G))
-    near9 = table[cid]                                      # [n, 9]
-    f = -_agg_field_9(pos, mu_full[near9], M_full[near9], C, L, md)
+    # every per-cell quantity in one [13, nc+1] plane with the cells on
+    # lanes; each cell's 3×3 neighborhood gathered once ([117, nc+1]), then
+    # fetched for every vertex by one gather along the lane axis into
+    # [13, 9, n] (vertices on lanes). Gathering each [nc+1] or [nc+1, 2]
+    # array by ``table[cid]`` instead puts 9 or 2 on the lane axis, and the
+    # TPU compiler fetches those element by element.
+    cells = jnp.concatenate([
+        mu_full.T, M_full[None],                            # 0:2, 2
+        mu_out.T, M_out[None], r_out[None],                 # 3:5, 5, 6
+        mu_in.T, M_in[None], r_in[None],                    # 7:9, 9, 10
+        S_out.T], axis=0)                                   # 11:13
+    F, nc1 = cells.shape
+    table = jnp.asarray(_neighbor_table(grid_dim).T)         # [9, nc+1]
+    cells9 = cells[:, table].reshape(F * 9, nc1)            # [117, nc+1]
+    g = cells9[:, cid].reshape(F, 9, -1)                    # [13, 9, n]
+    p = pos.T                                               # [2, n]
+    f = -_agg_field_9(p, g[0:2], g[2], C, L, md)
     # overflow add-back: an overflowed vertex sits inside its own cell's
     # overflow aggregate, which would exert a spurious self-force — remove
-    # its own (mass, position) from the center cell (table column 4) before
-    # evaluating.
-    m9 = M_out[near9]
-    mu9 = mu_out[near9]
+    # its own (mass, position) from the center cell before evaluating.
+    # Column 4 of the table is the cell itself (the sentinel's too), so
+    # block 4 of the gather holds M_out[cid] and S_out[cid].
     m_self = w_out                                          # w if overflowed
-    m_adj = jnp.maximum(M_out[cid] - m_self, 0.0)
-    s_adj = S_out[cid] - m_self[:, None] * pos
-    m9 = m9.at[:, 4].set(m_adj)
-    mu9 = mu9.at[:, 4].set(s_adj / jnp.maximum(m_adj, _EPS)[:, None])
-    f += _agg_field_9(pos, mu9, m9, C, L, md, r9=r_out[near9])
+    m_adj = jnp.maximum(g[5, 4] - m_self, 0.0)
+    s_adj = g[11:13, 4] - m_self[None, :] * p
+    m9 = g[5].at[4].set(m_adj)
+    mu9 = g[3:5].at[:, 4].set(s_adj / jnp.maximum(m_adj, _EPS)[None, :])
+    f += _agg_field_9(p, mu9, m9, C, L, md, r9=g[6])
     # an overflowed vertex also never met the *bucketed* vertices of its
     # 3×3 neighborhood (it has no bucket row of its own) — restore them as
     # softened in-bucket aggregates, gated to overflow vertices only
-    f_bkt = _agg_field_9(pos, mu_in[near9], M_in[near9], C, L, md,
-                         r9=r_in[near9])
-    return f + jnp.where(inb, 0.0, 1.0)[:, None] * f_bkt
+    f_bkt = _agg_field_9(p, g[7:9], g[9], C, L, md, r9=g[10])
+    return (f + jnp.where(inb, 0.0, 1.0)[None, :] * f_bkt).T
 
 
 def grid_repulsion(pos, mass, vmask, C, L, min_dist, *,

@@ -123,11 +123,11 @@ def test_grid_far_field_component_within_10pct():
     w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)
     cid, _, _ = bin_vertices(pos, vmask, G, cap)
     M, _, mu = _cell_aggregates(pos, w, cid, nc)
-    table = jnp.asarray(_neighbor_table(G))
+    near9 = jnp.asarray(_neighbor_table(G).T)[:, cid]      # [9, n]
     cell_xyw = jnp.concatenate([mu[:nc], M[:nc, None]], axis=1)
     f_far = np.asarray(
         _far_all_cells(pos, cell_xyw, C, L, md, "ref")
-        - _agg_field_9(pos, mu[table[cid]], M[table[cid]], C, L, md))
+        - _agg_field_9(pos.T, mu.T[:, near9], M[near9], C, L, md).T)
 
     # exact far field: all pairs minus pairs within the 3×3 neighborhood
     cid_np = np.asarray(cid)
@@ -220,12 +220,15 @@ def _centre_block_input(kind):
 
 
 def _parent_grid_repulsion(pos, mass, vmask, C, L, md, *, grid_dim,
-                           cell_cap):
+                           cell_cap, far_corrections=None):
     """``grid_repulsion`` as it was with two gathers: the rows fetched by
-    their own ``xyw_p[:2, bucket[:nc].T]``, apart from the partner planes."""
+    their own ``xyw_p[:2, bucket[:nc].T]``, apart from the partner planes.
+    ``far_corrections`` defaults to the module's."""
     from repro.kernels.grid_force.ops import (
         bin_vertices, cell_aggregates, cell_centers, neighbor_table,
-        near_field, far_all_cells, far_corrections)
+        near_field, far_all_cells)
+    if far_corrections is None:
+        from repro.kernels.grid_force.ops import far_corrections
     from repro.kernels import backend
     mode = backend()
     n = pos.shape[0]
@@ -300,3 +303,97 @@ def test_grid_repulsion_matches_two_gather_form(kind, backend, monkeypatch):
     got = grid_repulsion(*args, grid_dim=G, cell_cap=cap)
     want = _parent_grid_repulsion(*args, grid_dim=G, cell_cap=cap)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+def _parent_agg_field_9(pos, mu9, m9, C, L, md, r9=None):
+    """``_agg_field_9`` as it was, vertex-major: pos [n, 2], mu9 [n, 9, 2],
+    m9 [n, 9] → [n, 2]."""
+    dx = pos[:, 0][:, None] - mu9[..., 0]
+    dy = pos[:, 1][:, None] - mu9[..., 1]
+    d2 = dx * dx + dy * dy + md * md
+    if r9 is not None:
+        d2 = d2 + r9 * r9
+    inv = (C * L * L) * m9 / d2
+    return jnp.stack([jnp.sum(dx * inv, axis=1),
+                      jnp.sum(dy * inv, axis=1)], axis=1)
+
+
+def _parent_far_corrections(pos, w_out, cid, inb,
+                            M_full, S_full, Q_full, M_out, S_out, Q_out,
+                            C, L, md, *, grid_dim, centers):
+    """``far_corrections`` as it was: every per-cell quantity gathered on
+    its own by ``table[cid]`` into ``[n, 9]`` and ``[n, 9, 2]`` arrays."""
+    from repro.kernels.grid_force.ops import _EPS, _rms, neighbor_table
+    mu_full = S_full / jnp.maximum(M_full, _EPS)[:, None]
+    mu_out = S_out / jnp.maximum(M_out, _EPS)[:, None]
+    r_out = _rms(Q_out, M_out, S_out, centers)
+    M_in = M_full - M_out
+    S_in = S_full - S_out
+    mu_in = S_in / jnp.maximum(M_in, _EPS)[:, None]
+    r_in = _rms(Q_full - Q_out, M_in, S_in, centers)
+    near9 = jnp.asarray(neighbor_table(grid_dim))[cid]       # [n, 9]
+    f = -_parent_agg_field_9(pos, mu_full[near9], M_full[near9], C, L, md)
+    m9 = M_out[near9]
+    mu9 = mu_out[near9]
+    m_self = w_out
+    m_adj = jnp.maximum(M_out[cid] - m_self, 0.0)
+    s_adj = S_out[cid] - m_self[:, None] * pos
+    m9 = m9.at[:, 4].set(m_adj)
+    mu9 = mu9.at[:, 4].set(s_adj / jnp.maximum(m_adj, _EPS)[:, None])
+    f += _parent_agg_field_9(pos, mu9, m9, C, L, md, r9=r_out[near9])
+    f_bkt = _parent_agg_field_9(pos, mu_in[near9], M_in[near9], C, L, md,
+                                r9=r_in[near9])
+    return f + jnp.where(inb, 0.0, 1.0)[:, None] * f_bkt
+
+
+def _corrections_args(pos, mass, vmask, G, cap):
+    """``far_corrections``' arguments as ``grid_repulsion`` builds them."""
+    from repro.kernels.grid_force.ops import (bin_vertices, cell_aggregates,
+                                              cell_centers)
+    nc = G * G
+    w = jnp.where(vmask, mass, 0.0).astype(jnp.float32)
+    cid, _, inb = bin_vertices(pos, vmask, G, cap)
+    M_full, S_full, _ = cell_aggregates(pos, w, cid, nc)
+    w_out = jnp.where(inb, 0.0, w)
+    M_out, S_out, _ = cell_aggregates(pos, w_out, cid, nc)
+    centers = cell_centers(pos, vmask, G)
+    q = jnp.sum((pos - centers[cid]) ** 2, axis=1)
+    Q_full = jax.ops.segment_sum(w * q, cid, num_segments=nc + 1)
+    Q_out = jax.ops.segment_sum(w_out * q, cid, num_segments=nc + 1)
+    return ((pos, w_out, cid, inb, M_full, S_full, Q_full, M_out, S_out,
+             Q_out, 1.2, 0.9, 1e-2), centers, inb)
+
+
+@pytest.mark.parametrize("backend", ["ref", "interpret"])
+@pytest.mark.parametrize("kind", ["random", "cluster", "overflow"])
+def test_far_corrections_match_the_vertex_major_form(kind, backend,
+                                                     monkeypatch):
+    """The lane-major corrections (every per-cell quantity gathered into
+    ``[13, 9, n]`` at once) against the vertex-major ``[n, 9]`` gathers,
+    alone and inside the whole op; ``overflow`` bins the clusters at cell
+    cap 8, so most vertices overflow their buckets."""
+    from repro.kernels.grid_force.ops import far_corrections
+    monkeypatch.setenv("REPRO_PALLAS", backend)
+    pos, mass, vmask = _centre_block_input(
+        "random" if kind == "random" else "cluster")
+    G, cap = choose_grid(pos.shape[0])
+    if kind == "overflow":
+        cap = 8
+    args, centers, inb = _corrections_args(pos, mass, vmask, G, cap)
+    if kind != "random":
+        assert (~np.asarray(inb)).sum() > 300
+    kw = dict(grid_dim=G, centers=centers)
+    got = np.asarray(jax.jit(lambda *a: far_corrections(*a, **kw))(*args))
+    want = np.asarray(
+        jax.jit(lambda *a: _parent_far_corrections(*a, **kw))(*args))
+    # the same gathered values in the same float32 operations; only the
+    # order of each 9-term sum may differ, so the two agree to rounding
+    # of the largest correction
+    atol = 1e-6 * np.abs(want).max()
+    np.testing.assert_allclose(got, want, rtol=0, atol=atol)
+    f_args = (pos, mass, vmask, 1.2, 0.9, 1e-2)
+    f_got = grid_repulsion(*f_args, grid_dim=G, cell_cap=cap)
+    f_want = _parent_grid_repulsion(*f_args, grid_dim=G, cell_cap=cap,
+                                    far_corrections=_parent_far_corrections)
+    np.testing.assert_allclose(np.asarray(f_got), np.asarray(f_want),
+                               rtol=0, atol=atol)

@@ -8,6 +8,8 @@ the CPU. The topology is described inside a fixture, never at import, so
 every pytest-xdist worker collects the same tests and only the worker that
 runs this file loads the TPU library.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -200,3 +202,50 @@ def test_stress_grid_step_at_the_cells_finest_level(S, monkeypatch):
     assert not [ln for ln in loops if "grid.near/gather" in ln]
     point_index = f"s32[{G * G * cap},2]"
     assert not [ln for ln in loops if point_index in ln], point_index
+
+
+_HLO_RESULT = re.compile(r"= \w+\[([\d,]*)\]\{([\d,]*)")
+
+
+def _corrections_ops(text):
+    """The ``gather`` and ``while`` instructions under ``grid.corrections``,
+    and the minor dimension of each gather's result (its layout's first
+    entry)."""
+    gathers, loops = [], []
+    for ln in text.splitlines():
+        if "grid.corrections" not in ln:
+            continue
+        if " while(" in ln:
+            loops.append(ln)
+        elif " gather(" in ln:
+            dims, layout = _HLO_RESULT.search(ln).groups()
+            minor = int(dims.split(",")[int(layout.split(",")[0])])
+            gathers.append((minor, ln.strip()[:160]))
+    return gathers, loops
+
+
+@pytest.mark.parametrize("n,lanes", [(131072, 0), (16384, 8)],
+                         ids=["n131072", "batched8_n16384"])
+def test_grid_corrections_gather_on_lane_major_planes(S, monkeypatch, n,
+                                                      lanes):
+    """``far_corrections`` at the finest level of the ``offline`` cells
+    (131,072 vertices, G 105, cap 48) and in the 8-lane batched step at
+    16,384 (G 37): the per-cell quantities reach the vertices through at
+    most two gathers, each cell's 3x3 neighborhood and then one per
+    vertex, each with the cells or the vertices on the lane axis, never 2
+    or 9 there (a result with 2 or 9 minor is fetched element by
+    element), and no loop."""
+    from repro.kernels.grid_force.ops import choose_grid, grid_repulsion
+    monkeypatch.setenv("REPRO_PALLAS", "pallas")
+    G, cap = choose_grid(n)
+    assert (G, cap) == {131072: (105, 48), 16384: (37, 48)}[n]
+    f = lambda p, m, v: grid_repulsion(p, m, v, 1.0, 1.0, 1e-3,
+                                       grid_dim=G, cell_cap=cap)
+    specs = (S(n, 2), S(n), S(n, dt=bool))
+    if lanes:
+        f = jax.vmap(f)
+        specs = (S(lanes, n, 2), S(lanes, n), S(lanes, n, dt=bool))
+    gathers, loops = _corrections_ops(_compile_text(f, *specs))
+    assert 1 <= len(gathers) <= 2, gathers
+    assert not [g for g in gathers if g[0] in (2, 9)], gathers
+    assert not loops, loops
